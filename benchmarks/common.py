@@ -65,30 +65,31 @@ def roofline_fields(fn, us, *args):
 
     Lowers ``fn(*args)`` and runs the trip-count-aware HLO cost model over
     the compiled text, turning the measured microseconds into an
-    achieved-HBM-bandwidth fraction against the v5e roofline (analysis.HW),
-    plus the full Roofline term breakdown.  Best-effort: returns ``{}``
-    when the callable can't be lowered to costable HLO (interpret-mode
-    Pallas bodies always can — the cost model reads the HLO custom-call
-    wrapper's operands)."""
+    achieved-HBM-bandwidth fraction against the running chip's roofline
+    (``analysis.PEAKS``), plus the full Roofline term breakdown.  Returns
+    ``{}`` on a device with no published peaks (a CPU run has no roofline
+    share)."""
     from repro.roofline import hlo_cost
-    from repro.roofline.analysis import HW, roofline
-    try:
-        txt = jax.jit(fn).lower(*args).compile().as_text()
-        cost = hlo_cost.analyze(txt, n_chips=1)
-    except Exception:
+    from repro.roofline.analysis import PEAKS, roofline
+    kind = jax.devices()[0].device_kind
+    if kind not in PEAKS:
         return {}
+    txt = jax.jit(fn).lower(*args).compile().as_text()
+    cost = hlo_cost.analyze(txt, n_chips=1)
     secs = us / 1e6
     achieved = cost.bytes_accessed / secs if secs > 0 else 0.0
-    r = roofline(cost.flops, cost.bytes_accessed, 0.0, 1, cost.flops)
+    r = roofline(cost.flops, cost.bytes_accessed, 0.0, 1, cost.flops,
+                 device_kind=kind)
     return dict(bytes_accessed=cost.bytes_accessed,
                 achieved_gbps=achieved / 1e9,
-                roofline_frac=achieved / HW["hbm_bw"],
+                roofline_frac=achieved / PEAKS[kind]["hbm_bw"],
                 roofline=r.as_dict())
 
 
 def calibration_us():
-    """Median time of a fixed Pallas-interpret SELL kernel call — the
-    machine-speed yardstick recorded in the JSON payload.
+    """Median time of a fixed Pallas SELL kernel call (compiled on a TPU,
+    interpreted elsewhere) — the machine-speed yardstick recorded in the
+    JSON payload.
 
     ``check_regression.py`` rescales a baseline captured on different
     hardware by the calibration ratio before applying its slowdown factor
@@ -99,12 +100,31 @@ def calibration_us():
     throughput can differ 2x in dispatch overhead."""
     import jax.numpy as jnp
     from repro.kernels.dsc import dsc_sell_pallas
-    atoms = jnp.zeros((64, 32), jnp.int32)
-    scaled = jnp.ones((64, 32), jnp.float32)
+    from repro.kernels.ops import interpret_mode
+    atoms = jnp.zeros((32, 128), jnp.int32)      # slot-major (width, rows)
+    scaled = jnp.ones((32, 128), jnp.float32)
     d = jnp.ones((32, 128), jnp.float32)
-    f = jax.jit(lambda a, s: dsc_sell_pallas(a, s, d, row_tile=8,
-                                             slot_tile=16, interpret=True))
+    f = jax.jit(lambda a, s: dsc_sell_pallas(a, s, d, slot_tile=16,
+                                             interpret=interpret_mode()))
     return time_fn(f, atoms, scaled, warmup=2, repeats=5)
+
+
+def fitting_meshes(table, topologies=((1, 1), (2, 1), (2, 2), (4, 2))):
+    """``(n_devices, (R, C), mesh)`` for each (data, model) topology that
+    fits the devices of this process; prints a line for each one that does
+    not."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    have = jax.devices()
+    for R, C in topologies:
+        n = R * C
+        if n > len(have):
+            print(f"# {table}: skip {R}x{C} mesh: needs {n} devices, "
+                  f"have {len(have)}", file=sys.stderr)
+            continue
+        mesh = Mesh(np.asarray(have[:n]).reshape(R, C), ("data", "model"),
+                    axis_types=(AxisType.Auto,) * 2)
+        yield n, (R, C), mesh
 
 
 def problem(scale="bench"):

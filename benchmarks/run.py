@@ -35,6 +35,7 @@ import jax
 
 from benchmarks import common
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from benchmarks import (table2_restructuring, table3_partitioning,
                         table4_opt_combos, table5_scaling,
                         table8_kernel_ladder, table9_param_sweep,
@@ -119,6 +120,7 @@ def main(argv=None) -> None:
         ap.error(f"unknown tables {unknown}; choose from {sorted(TABLES)}")
     wanted = args.tables or list(TABLES)
 
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
     common.reset_results()
     obs.enable()
     obs.reset()

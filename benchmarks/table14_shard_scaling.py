@@ -1,72 +1,43 @@
 """Beyond-paper: sharded-serving throughput vs device count (DESIGN.md §9).
 
 Subjects/sec through the format-aware sharded executors (`shard` over inner
-COO cells, `shard-sell` over per-cell SELL tiles) on 1/2/4/8 forced host
-devices, one subprocess per topology (XLA_FLAGS must be set before jax
-imports).  The container has one physical core, so wall times measure the
-*schedule*; the derived column therefore also reports the per-cell padding
-overhead — the quantity the equal-nnz partition and the per-cell layout
-trade against each other.
+COO cells, `shard-sell` over per-cell SELL tiles) on 1/2/4/8-device meshes
+in this process, over the devices that exist (one process owns the chip).
+A topology that needs more devices than exist is skipped with a printed
+line; one that fails fails the run.  The derived column also reports the
+per-cell padding overhead — the quantity the equal-nnz partition and the
+per-cell layout trade against each other.
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import time
 
-from benchmarks.common import emit
-
-_CODE = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
-import sys; sys.path.insert(0, {src!r})
-import time, json, dataclasses
-import numpy as np, jax
-from repro.data.dmri import synth_cohort
+from benchmarks.common import emit, fitting_meshes
 from repro.core.life import LifeConfig, LifeEngine
+from repro.data.dmri import synth_cohort
 
-R, C = {rc}
-cohort = synth_cohort(1, base_seed=7, n_fibers=256, n_theta=32, n_atoms=32,
-                      grid=(12, 12, 12))
 REPEATS = 3
-out = {{}}
-for name, fmt in (("shard", "coo"), ("shard-sell", "sell")):
-    cfg = LifeConfig(executor=name, format=fmt, shard_rows=R, shard_cols=C,
-                     n_iters=10, slot_tile=16, plan_cache_dir="")
-    # one engine per topology: time the sharded *solve*, not per-engine
-    # trace/compile + host encoding (those are amortized by the plan cache
-    # and jit cache in a serving deployment)
-    eng = LifeEngine(cohort[0], cfg)
-    eng.run(2)                                  # compile both SpMV closures
-    t0 = time.perf_counter()
-    for _ in range(REPEATS):
-        eng.run()                               # one full subject solve
-    dt = time.perf_counter() - t0
-    sp = eng.executor.plans["shard_dsc"]
-    out[name] = dict(subjects_per_sec=REPEATS / dt,
-                     padding_overhead=sp.padding_overhead)
-print(json.dumps(out))
-"""
 
 
 def run():
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    for n, rc in ((1, (1, 1)), (2, (2, 1)), (4, (2, 2)), (8, (4, 2))):
-        code = _CODE.format(n=n, src=os.path.abspath(src), rc=rc)
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                              capture_output=True, text=True, env=env,
-                              timeout=1200)
-        if proc.returncode != 0:
-            emit(f"table14.devices{n}", 0.0, "ERROR")
-            continue
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
-        for name, r in rec.items():
-            emit(f"table14.{name}.devices{n}",
-                 1e6 / max(r["subjects_per_sec"], 1e-9),
-                 f"subjects_per_sec={r['subjects_per_sec']:.3f};"
-                 f"padding_overhead={r['padding_overhead']:.2f}")
+    cohort = synth_cohort(1, base_seed=7, n_fibers=256, n_theta=32,
+                          n_atoms=32, grid=(12, 12, 12))
+    for n, (R, C), _ in fitting_meshes("table14"):
+        for name, fmt in (("shard", "coo"), ("shard-sell", "sell")):
+            cfg = LifeConfig(executor=name, format=fmt, shard_rows=R,
+                             shard_cols=C, n_iters=10, slot_tile=16,
+                             plan_cache_dir="")
+            # one engine per topology: time the sharded *solve*, not
+            # per-engine trace/compile + host encoding (those are amortized
+            # by the plan cache and jit cache in a serving deployment)
+            eng = LifeEngine(cohort[0], cfg)
+            eng.run(2)                          # compile both SpMV closures
+            t0 = time.perf_counter()
+            for _ in range(REPEATS):
+                eng.run()                       # one full subject solve
+            sps = REPEATS / (time.perf_counter() - t0)
+            sp = eng.executor.plans["shard_dsc"]
+            emit(f"table14.{name}.devices{n}", 1e6 / sps,
+                 f"subjects_per_sec={sps:.3f};"
+                 f"padding_overhead={sp.padding_overhead:.2f}")
 
 
 if __name__ == "__main__":

@@ -1,71 +1,43 @@
 """Paper Table 5: scaling with worker count (threads -> mesh devices).
 
-Runs the 2-D shard_map SBBNNLS on 1/2/4/8 host devices in subprocesses
-(XLA_FLAGS per process).  The container has one physical core, so wall times
-measure the *schedule* (no real parallel speedup is possible); the derived
-column therefore reports the per-device coefficient share — the quantity the
-paper's sync-free mapping balances — alongside the time.
+Runs the 2-D shard_map SBBNNLS on 1/2/4/8-device meshes in this process,
+over the devices that exist (one process owns the chip, so topologies
+cannot be spread over child processes).  A topology that needs more devices
+than exist is skipped with a printed line; one that fails fails the run.
+The derived column reports the per-device coefficient share — the quantity
+the paper's sync-free mapping balances — alongside the time.
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import time
 
-from benchmarks.common import emit
+import jax
+import jax.numpy as jnp
 
-_CODE = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
-import sys; sys.path.insert(0, {src!r})
-import time, json
-import numpy as np, jax, jax.numpy as jnp
-from repro.data.dmri import synth_connectome
+from benchmarks.common import emit, fitting_meshes, problem
 from repro.distributed import life_shard as LS
-
-p = synth_connectome(n_fibers=1024, n_theta=96, n_atoms=96,
-                     grid=(20, 20, 20), algorithm="PROB", seed=5)
-R, C = {rc}
-from repro import compat
-mesh = compat.make_mesh((R, C), ("data", "model"))
-shards = LS.build_life_shards(p.phi, 96, R=R, C=C)
-step = LS.make_sharded_step(mesh, dict(nv_local=shards.nv_local,
-                                       nf_local=shards.nf_local, n_theta=96))
-args = LS.sharded_state(mesh, shards, p)
-jstep = jax.jit(step)
-w = args["w"]
-with mesh:
-    for it in range(3):   # warmup/compile
-        w, loss = jstep(args["da"],args["dv"],args["df"],args["dw"],
-                        args["wa"],args["wv"],args["wf"],args["ww"],
-                        args["d"], args["b"], w, jnp.asarray(it, jnp.int32))
-    loss.block_until_ready()
-    t0 = time.perf_counter()
-    for it in range(10):
-        w, loss = jstep(args["da"],args["dv"],args["df"],args["dw"],
-                        args["wa"],args["wv"],args["wf"],args["ww"],
-                        args["d"], args["b"], w, jnp.asarray(it, jnp.int32))
-    loss.block_until_ready()
-    dt = (time.perf_counter() - t0) / 10
-print(json.dumps(dict(us=dt*1e6, nnz_cell=int(shards.dsc_values.shape[-1]))))
-"""
 
 
 def run():
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    for n, rc in ((1, (1, 1)), (2, (2, 1)), (4, (2, 2)), (8, (4, 2))):
-        code = _CODE.format(n=n, src=os.path.abspath(src), rc=rc)
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                              capture_output=True, text=True, env=env,
-                              timeout=600)
-        if proc.returncode != 0:
-            emit(f"table5.devices{n}", 0.0, "ERROR")
-            continue
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
-        emit(f"table5.devices{n}", rec["us"],
-             f"nnz_per_cell={rec['nnz_cell']}")
+    p = problem()
+    for n, (R, C), mesh in fitting_meshes("table5"):
+        shards = LS.build_life_shards(p.phi, 96, R=R, C=C)
+        step = LS.make_sharded_step(mesh, dict(nv_local=shards.nv_local,
+                                               nf_local=shards.nf_local,
+                                               n_theta=96))
+        args = LS.sharded_state(mesh, shards, p)
+        jstep = jax.jit(step)
+        cells = [args[k] for k in ("da", "dv", "df", "dw",
+                                   "wa", "wv", "wf", "ww", "d", "b")]
+        w = args["w"]
+        for it in range(3):   # warmup/compile
+            w, loss = jstep(*cells, w, jnp.asarray(it, jnp.int32))
+        loss.block_until_ready()
+        t0 = time.perf_counter()
+        for it in range(10):
+            w, loss = jstep(*cells, w, jnp.asarray(it, jnp.int32))
+        loss.block_until_ready()
+        us = (time.perf_counter() - t0) / 10 * 1e6
+        emit(f"table5.devices{n}", us,
+             f"nnz_per_cell={int(shards.dsc_values.shape[-1])}")
 
 
 if __name__ == "__main__":

@@ -18,8 +18,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
-from repro import compat
 from repro.core.life import LifeConfig, LifeEngine
 from repro.data.dmri import synth_connectome
 from repro.distributed import life_shard as LS
@@ -29,7 +29,8 @@ def main():
     problem = synth_connectome(n_fibers=512, n_theta=96, n_atoms=96,
                                grid=(16, 16, 16), algorithm="PROB", seed=0)
     R, C = 4, 2
-    mesh = compat.make_mesh((R, C), ("data", "model"))
+    mesh = jax.make_mesh((R, C), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     print(f"mesh: {dict(mesh.shape)} over {mesh.devices.size} devices")
 
     t0 = time.time()

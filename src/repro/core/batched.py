@@ -146,8 +146,9 @@ class BatchedLifeEngine:
             raise ValueError(
                 f"batched mesh needs {R * C} devices, "
                 f"have {len(jax.devices())}")
-        from repro import compat
-        return compat.make_mesh((R, C), ("data", "model"))
+        from jax.sharding import AxisType
+        return jax.make_mesh((R, C), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
     def _place_on_mesh(self) -> None:
         """Subjects over the batch (`data`) axis, Phi slots over `model`.

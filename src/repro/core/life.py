@@ -9,7 +9,7 @@ ladder (paper §6.3.1/§6.4.1), selectable via ``executor=``:
   opt          TPU-opt (ours)   : output-side sorts for both ops
                                   (DSC voxel-sorted, WC fiber-sorted)
   kernel       TPU Pallas       : inspector-planned tiled kernels
-                                  (interpret=True off-TPU)
+                                  (compiled on TPU, interpreted elsewhere)
   auto         runtime autotune : measured selection (paper's hybrid/runtime
                                   choice, §4.1.2)
   shard        mesh partition   : 2-D shard_map SpMVs over inner sorted-COO
@@ -68,7 +68,6 @@ class LifeConfig:
     compact_threshold: float = 0.0
     c_tile: int = 256               # kernel coefficient-tile size
     row_tile: int = 8               # kernel output row-block size
-    kernel_interpret: bool = True   # CPU container: validate via interpret
     # mesh geometry (R, C) for the sharded executors; with R*C > 1 the
     # format="auto" candidate set and executor mapping become mesh-aware
     # (formats/select.py picks among formats with a registered mesh executor)
@@ -155,7 +154,7 @@ class LifeEngine:
         # held instruments for the hot step loop (no-ops while disabled);
         # HLO byte counts are invalidated here because compaction rebinds
         # the SpMV closures over a smaller Phi
-        self._op_bytes: Optional[dict] = None
+        self._op_bytes: Optional[float] = None
         self._h_step = obs.histogram("engine.step.seconds",
                                      executor=self.executor.name)
         self._g_frac = obs.gauge("engine.roofline.fraction",
@@ -238,23 +237,24 @@ class LifeEngine:
         Bytes per SBBNNLS iteration follow the tuner's dominant-op mix
         (DSC every iteration + line-search probe, WC on alternation):
         ``DSC_WEIGHT * dsc_bytes + WC_WEIGHT * wc_bytes``.  Fraction is
-        against the roofline model's HBM bandwidth (analysis.HW)."""
-        bytes_per_iter = self._op_bytes_per_iter()
-        if bytes_per_iter is None or dt <= 0.0:
+        against the running chip's HBM bandwidth (``analysis.PEAKS``); on a
+        device with no published peaks (a CPU) nothing is written."""
+        from repro.roofline.analysis import PEAKS
+        kind = jax.devices()[0].device_kind
+        if kind not in PEAKS or dt <= 0.0:
             return
-        from repro.roofline.analysis import HW
+        bytes_per_iter = self._op_bytes_per_iter()
         achieved = bytes_per_iter * k / dt
-        frac = achieved / HW["hbm_bw"]
+        frac = achieved / PEAKS[kind]["hbm_bw"]
         self._g_bw.set(achieved / 1e9)
         self._g_frac.set(frac)
         sp.set_attr("bytes_accessed", bytes_per_iter * k)
         sp.set_attr("achieved_gbps", achieved / 1e9)
         sp.set_attr("roofline_fraction", frac)
 
-    def _op_bytes_per_iter(self) -> Optional[float]:
+    def _op_bytes_per_iter(self) -> float:
         """Weighted HBM bytes of one SBBNNLS iteration, from the compiled
-        HLO of the bound SpMV pair (lazy, memoized until the next _build;
-        None when either op can't be lowered/costed)."""
+        HLO of the bound SpMV pair (lazy, memoized until the next _build)."""
         if self._op_bytes is None:
             from repro.roofline import hlo_cost
             from repro.tune.tuner import DSC_WEIGHT, WC_WEIGHT
@@ -262,19 +262,14 @@ class LifeEngine:
             probes = ((self.matvec, jnp.ones((self.phi.n_fibers,), d.dtype)),
                       (self.rmatvec,
                        jnp.ones((self.phi.n_voxels, d.shape[1]), d.dtype)))
-            try:
-                dsc_b, wc_b = (
-                    hlo_cost.analyze(
-                        jax.jit(fn).lower(probe).compile().as_text(),
-                        n_chips=1).bytes_accessed
-                    for fn, probe in probes)
-                self._op_bytes = dict(
-                    per_iter=DSC_WEIGHT * dsc_b + WC_WEIGHT * wc_b)
-            except Exception:
-                # interpret-mode kernels / exotic layouts may not lower to
-                # costable HLO — roofline annotation is best-effort
-                self._op_bytes = dict(per_iter=None)
-        return self._op_bytes["per_iter"]
+            apply = jax.jit(lambda fn, x: fn(x))
+            dsc_b, wc_b = (
+                hlo_cost.analyze(
+                    apply.lower(fn, probe).compile().as_text(),
+                    n_chips=1).bytes_accessed
+                for fn, probe in probes)
+            self._op_bytes = DSC_WEIGHT * dsc_b + WC_WEIGHT * wc_b
+        return self._op_bytes
 
     def run(self, n_iters: Optional[int] = None,
             w0: Optional[jax.Array] = None) -> Tuple[jax.Array, np.ndarray]:

@@ -48,6 +48,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.core import spmv
 from repro.core.inspector import plan_tiles
@@ -61,7 +62,12 @@ MatVec = Callable[[Array], Array]
 
 @dataclasses.dataclass
 class Executor:
-    """A bound pair of SpMV closures plus inspector diagnostics."""
+    """A bound pair of SpMV closures plus inspector diagnostics.
+
+    Built-in factories return ``jax.tree_util.Partial`` closures whose bound
+    arrays (Phi layouts, plans, the dictionary) are pytree leaves, so the
+    solver passes them to its compiled program as arguments
+    (``core/sbbnnls.py``)."""
 
     name: str
     matvec: MatVec                        # w (Nf,) -> y (Nv, Ntheta)
@@ -207,8 +213,8 @@ def _make_naive(phi, problem, config, cache) -> Executor:
     phi, d = _with_storage_dtype(phi, problem.dictionary, config)
     return Executor(
         name="naive",
-        matvec=lambda w: spmv.dsc_naive(phi, d, w),
-        rmatvec=lambda y: spmv.wc_naive(phi, d, y),
+        matvec=Partial(spmv.dsc_naive, phi, d),
+        rmatvec=Partial(spmv.wc_naive, phi, d),
         vmappable=True)
 
 
@@ -224,8 +230,8 @@ def _make_opt(phi, problem, config, cache) -> Executor:
     phi_v, phi_w, _, _ = _sorted_pair(phi, "fiber")
     return Executor(
         name="opt",
-        matvec=lambda w: spmv.dsc(phi_v, d, w),
-        rmatvec=lambda y: spmv.wc(phi_w, d, y),
+        matvec=Partial(spmv.dsc, phi_v, d),
+        rmatvec=Partial(spmv.wc, phi_w, d),
         vmappable=True)
 
 
@@ -235,8 +241,8 @@ def _make_opt_paper(phi, problem, config, cache) -> Executor:
     phi_v, phi_w, _, _ = _sorted_pair(phi, "atom")
     return Executor(
         name="opt-paper",
-        matvec=lambda w: spmv.dsc(phi_v, d, w),
-        rmatvec=lambda y: spmv.wc_atom_sorted(phi_w, d, y),
+        matvec=Partial(spmv.dsc, phi_v, d),
+        rmatvec=Partial(spmv.wc_atom_sorted, phi_w, d),
         vmappable=True)
 
 
@@ -265,12 +271,8 @@ def _make_kernel(phi, problem, config, cache) -> Executor:
     cd = _compute_dtype(config)
     return Executor(
         name="kernel",
-        matvec=kops.make_dsc(phi_v, d, dsc_plan,
-                             interpret=config.kernel_interpret,
-                             compute_dtype=cd),
-        rmatvec=kops.make_wc(phi_w, d, wc_plan,
-                             interpret=config.kernel_interpret,
-                             compute_dtype=cd),
+        matvec=kops.make_dsc(phi_v, d, dsc_plan, compute_dtype=cd),
+        rmatvec=kops.make_wc(phi_w, d, wc_plan, compute_dtype=cd),
         plans=dict(dsc_tiles=dsc_plan, wc_tiles=wc_plan))
 
 
@@ -293,12 +295,8 @@ def _make_kernel_sell(phi, problem, config, cache) -> Executor:
     cd = _compute_dtype(config)
     return Executor(
         name="kernel-sell",
-        matvec=kops.make_dsc_sell(sell_dsc, d,
-                                  interpret=config.kernel_interpret,
-                                  compute_dtype=cd),
-        rmatvec=kops.make_wc_sell(sell_wc, d,
-                                  interpret=config.kernel_interpret,
-                                  compute_dtype=cd),
+        matvec=kops.make_dsc_sell(sell_dsc, d, compute_dtype=cd),
+        rmatvec=kops.make_wc_sell(sell_wc, d, compute_dtype=cd),
         plans=dict(sell_dsc=sell_dsc, sell_wc=sell_wc))
 
 
@@ -315,8 +313,7 @@ def _make_kernel_fcoo(phi, problem, config, cache) -> Executor:
     fc = FcooPhi.encode(phi, c_tile=config.c_tile,
                         seg_tile=getattr(config, "seg_tile", 16))
     matvec, rmatvec = kops.make_fcoo_ops(
-        fc, problem.dictionary, interpret=config.kernel_interpret,
-        compute_dtype=_compute_dtype(config))
+        fc, problem.dictionary, compute_dtype=_compute_dtype(config))
     return Executor(name="kernel-fcoo", matvec=matvec, rmatvec=rmatvec,
                     plans=dict(fcoo=fc))
 
@@ -337,8 +334,8 @@ def _make_alto(phi, problem, config, cache) -> Executor:
     meta = dict(n_coeffs=enc.n_coeffs, nbytes=enc.nbytes)
     return Executor(
         name="alto",
-        matvec=lambda w: spmv.dsc_naive(phi_lin, d, w),
-        rmatvec=lambda y: spmv.wc_naive(phi_lin, d, y),
+        matvec=Partial(spmv.dsc_naive, phi_lin, d),
+        rmatvec=Partial(spmv.wc_naive, phi_lin, d),
         plans=dict(alto=meta),
         vmappable=True)
 
@@ -411,8 +408,8 @@ def _make_auto(phi, problem, config, cache) -> Executor:
     wc_fn = _WC_FNS[wc_plan.restructure]
     return Executor(
         name="auto",
-        matvec=lambda w: dsc_fn(phi_v, d, w),
-        rmatvec=lambda y: wc_fn(phi_w, d, y),
+        matvec=Partial(dsc_fn, phi_v, d),
+        rmatvec=Partial(wc_fn, phi_w, d),
         plans=dict(dsc=dsc_plan, wc=wc_plan),
         vmappable=True)
 
@@ -442,10 +439,14 @@ def _make_shard_executor(phi, problem, config, cache,
     global<->padded layout maps so callers see plain (Nf,) -> (Nv, Ntheta)
     closures.  The partition plan is persistent-cache-backed under a key
     that includes the mesh shape, the inner format, and the device count.
+    Each cell array is placed with a ``NamedSharding`` over the mesh, so
+    every device holds only its own cells.
     """
-    from repro import compat
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
     from repro.distributed import life_shard as LS
     from repro.formats.shard import encode_pair, partition_cuts
+    from repro.kernels.ops import bind, pad_lanes, sell_lanes, storage_cast
 
     R = getattr(config, "shard_rows", 1)
     C = getattr(config, "shard_cols", 1)
@@ -454,7 +455,8 @@ def _make_shard_executor(phi, problem, config, cache,
         raise ValueError(
             f"{name} executor needs {R * C} devices, "
             f"have {len(jax.devices())}")
-    mesh = compat.make_mesh((R, C), ("data", "model"))
+    mesh = jax.make_mesh((R, C), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     d = problem.dictionary
     n_theta = d.shape[1]
     cd = _compute_dtype(config)
@@ -466,61 +468,49 @@ def _make_shard_executor(phi, problem, config, cache,
     meta = dict(nv_local=plan.nv_local, nf_local=plan.nf_local,
                 n_theta=n_theta)
 
-    w_pos, y_pos = _layout_positions(plan, phi.n_voxels, phi.n_fibers)
-    w_pos_j = jnp.asarray(w_pos)
-    y_pos_j = jnp.asarray(y_pos)
     nf_pad = C * plan.nf_local
     nv_pad = R * plan.nv_local
 
     if cell_format == "coo":
-        from repro.kernels.ops import storage_cast
         dsc_sm, wc_sm = LS.make_sharded_ops(mesh, meta)
-        cell = tuple(storage_cast(sp_dsc.arrays[k], cd) if k == "values"
-                     else jnp.asarray(sp_dsc.arrays[k])
-                     for k in ("atoms", "voxels", "fibers", "values"))
-        wcell = tuple(storage_cast(sp_wc.arrays[k], cd) if k == "values"
-                      else jnp.asarray(sp_wc.arrays[k])
-                      for k in ("atoms", "voxels", "fibers", "values"))
+        keys = ("atoms", "voxels", "fibers", "values")
+        layout, lanes = np.asarray, (lambda y: y)
         d_op = storage_cast(d, cd)
-
-        def run_dsc(w_padded):
-            return dsc_sm(*cell, d_op, w_padded)
-
-        def run_wc(y_padded):
-            return wc_sm(*wcell, d_op, y_padded)
     else:
-        from repro.kernels.ops import pad_lanes, storage_cast
         dsc_sm, wc_sm = LS.make_sharded_sell_ops(
-            mesh, meta, row_tile=row_tile, slot_tile=slot_tile,
-            out_dtype=d.dtype,
-            interpret=getattr(config, "kernel_interpret", True))
-        cell = (jnp.asarray(sp_dsc.arrays["atoms"]),
-                jnp.asarray(sp_dsc.arrays["others"]),
-                storage_cast(sp_dsc.arrays["values"], cd))
-        wcell = (jnp.asarray(sp_wc.arrays["atoms"]),
-                 jnp.asarray(sp_wc.arrays["others"]),
-                 storage_cast(sp_wc.arrays["values"], cd))
+            mesh, meta, slot_tile=slot_tile, out_dtype=d.dtype)
+        keys = ("atoms", "others", "values")
+        layout, lanes = sell_lanes, pad_lanes
         d_op = pad_lanes(storage_cast(d, cd))
 
-        def run_dsc(w_padded):
-            return dsc_sm(*cell, d_op, w_padded)[:, :n_theta]
+    def place(arrays):
+        """Cell arrays onto the mesh, one (data, model) cell per device."""
+        out = []
+        for k in keys:
+            x = layout(arrays[k])
+            x = storage_cast(x, cd) if k == "values" else jnp.asarray(x)
+            spec = P("data", "model", *([None] * (x.ndim - 2)))
+            out.append(jax.device_put(x, NamedSharding(mesh, spec)))
+        return tuple(out)
+    w_pos, y_pos = _layout_positions(plan, phi.n_voxels, phi.n_fibers)
+    replicated = NamedSharding(mesh, P())
+    shared = dict(d=jax.device_put(d_op, replicated),
+                  w_pos=jax.device_put(w_pos, replicated),
+                  y_pos=jax.device_put(y_pos, replicated))
 
-        def run_wc(y_padded):
-            return wc_sm(*wcell, d_op, pad_lanes(y_padded))
+    def matvec(op, w: Array) -> Array:
+        w_padded = jnp.zeros((nf_pad,), w.dtype).at[op["w_pos"]].set(w)
+        y_padded = dsc_sm(*op["cell"], op["d"], w_padded)[:, :n_theta]
+        return jnp.take(y_padded, op["y_pos"], axis=0)
 
-    @jax.jit
-    def matvec(w: Array) -> Array:
-        w_padded = jnp.zeros((nf_pad,), w.dtype).at[w_pos_j].set(w)
-        y_padded = run_dsc(w_padded)
-        return jnp.take(y_padded, y_pos_j, axis=0)
-
-    @jax.jit
-    def rmatvec(y: Array) -> Array:
+    def rmatvec(op, y: Array) -> Array:
         y_padded = jnp.zeros((nv_pad, y.shape[1]), y.dtype
-                             ).at[y_pos_j].set(y)
-        w_padded = run_wc(y_padded)
-        return jnp.take(w_padded, w_pos_j)
+                             ).at[op["y_pos"]].set(y)
+        w_padded = wc_sm(*op["cell"], op["d"], lanes(y_padded))
+        return jnp.take(w_padded, op["w_pos"])
 
+    matvec = bind(matvec, dict(shared, cell=place(sp_dsc.arrays)))
+    rmatvec = bind(rmatvec, dict(shared, cell=place(sp_wc.arrays)))
     return Executor(name=name, matvec=matvec, rmatvec=rmatvec,
                     plans=dict(mesh=mesh, partition=plan,
                                shard_dsc=sp_dsc, shard_wc=sp_wc))

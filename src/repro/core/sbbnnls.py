@@ -20,8 +20,11 @@ from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.tree_util import Partial
 
 Array = jax.Array
+# fp32 dot products at full precision: the TPU default rounds operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 MatVec = Callable[[Array], Array]
 
 
@@ -64,7 +67,7 @@ def sbbnnls_step(matvec: MatVec, rmatvec: MatVec, b: Array,
 
 
 def _dot(a: Array, b: Array) -> Array:
-    return jnp.vdot(a.reshape(-1), b.reshape(-1))
+    return jnp.vdot(a.reshape(-1), b.reshape(-1), precision=HIGHEST)
 
 
 def _safe_div(num: Array, den: Array) -> Array:
@@ -77,7 +80,6 @@ def sbbnnls_init(w0: Array) -> SbbnnlsState:
                         loss=jnp.asarray(0.0, w0.dtype))
 
 
-@partial(jax.jit, static_argnames=("matvec", "rmatvec", "n_iters"))
 def sbbnnls_steps(matvec: MatVec, rmatvec: MatVec, b: Array,
                   state: SbbnnlsState, n_iters: int
                   ) -> Tuple[SbbnnlsState, Array]:
@@ -86,7 +88,24 @@ def sbbnnls_steps(matvec: MatVec, rmatvec: MatVec, b: Array,
     odd/even alternation continues where it left off: composing
     ``k x (n/k)`` calls is exactly one ``n``-iteration run, which is what
     makes time-sliced and checkpoint-resumed solves bit-compatible with
-    uninterrupted ones (serve/ relies on this)."""
+    uninterrupted ones (serve/ relies on this).
+
+    ``matvec``/``rmatvec`` that are :class:`jax.tree_util.Partial` objects
+    (every registry executor's are) pass their bound arrays — Phi, tile
+    plans, the dictionary — as arguments of the compiled program, so they
+    keep their device placement and are not baked into it as constants.
+    Any other callable is compiled as a static function."""
+    return _steps(_as_partial(matvec), _as_partial(rmatvec), b, state,
+                  n_iters)
+
+
+def _as_partial(fn: MatVec) -> Partial:
+    return fn if isinstance(fn, Partial) else Partial(fn)
+
+
+@partial(jax.jit, static_argnames=("n_iters",))
+def _steps(matvec: Partial, rmatvec: Partial, b: Array, state: SbbnnlsState,
+           n_iters: int) -> Tuple[SbbnnlsState, Array]:
     def body(s, _):
         new = sbbnnls_step(matvec, rmatvec, b, s)
         return new, new.loss

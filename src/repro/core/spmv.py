@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from repro.core.std import PhiTensor
 
 Array = jax.Array
+# fp32 dot products at full precision: the TPU default rounds operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ----------------------------------------------------------------------------
@@ -41,7 +43,8 @@ def dsc_naive(phi: PhiTensor, dictionary: Array, w: Array) -> Array:
 @partial(jax.jit, static_argnames=())
 def wc_naive(phi: PhiTensor, dictionary: Array, y: Array) -> Array:
     """w = M^T y via gather-dot-scatter, no restructuring assumed. (Nf,)."""
-    dots = jnp.einsum("ct,ct->c", dictionary[phi.atoms], y[phi.voxels])
+    dots = jnp.einsum("ct,ct->c", dictionary[phi.atoms], y[phi.voxels],
+                      precision=HIGHEST)
     vals = dots * phi.values
     out = jnp.zeros((phi.n_fibers,), vals.dtype)
     return out.at[phi.fibers].add(vals)
@@ -84,6 +87,7 @@ def wc(phi_sorted: PhiTensor, dictionary: Array, y: Array) -> Array:
         "ct,ct->c",
         jnp.take(dictionary, phi_sorted.atoms, axis=0),
         jnp.take(y, phi_sorted.voxels, axis=0),
+        precision=HIGHEST,
     )
     vals = dots * phi_sorted.values
     return segment_sum_sorted(vals, phi_sorted.fibers, phi_sorted.n_fibers)
@@ -96,6 +100,7 @@ def wc_atom_sorted(phi_sorted: PhiTensor, dictionary: Array, y: Array) -> Array:
         "ct,ct->c",
         jnp.take(dictionary, phi_sorted.atoms, axis=0),
         jnp.take(y, phi_sorted.voxels, axis=0),
+        precision=HIGHEST,
     )
     vals = dots * phi_sorted.values
     out = jnp.zeros((phi_sorted.n_fibers,), vals.dtype)

@@ -34,12 +34,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.sbbnnls import projected_gradient
 from repro.core.std import PhiTensor
 from repro.data.dmri import LifeProblem
 
 Array = jax.Array
+# fp32 dot products at full precision: the TPU default rounds operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass
@@ -151,16 +152,16 @@ def make_sharded_step(mesh: Mesh, shards_meta: Dict[str, int],
 
     def wc_local(a, v, f, w_vals, d, y_loc):
         dots = jnp.einsum("ct,ct->c", jnp.take(d, a, axis=0),
-                          jnp.take(y_loc, v, axis=0))
+                          jnp.take(y_loc, v, axis=0), precision=HIGHEST)
         w = jax.ops.segment_sum(dots * w_vals, f, num_segments=nf_l,
                                 indices_are_sorted=True)
         return jax.lax.psum(w, rows)
 
     def dot_y(x, y):
-        return jax.lax.psum(jnp.vdot(x, y), rows)
+        return jax.lax.psum(jnp.vdot(x, y, precision=HIGHEST), rows)
 
     def dot_w(x, y):
-        return jax.lax.psum(jnp.vdot(x, y), "model")
+        return jax.lax.psum(jnp.vdot(x, y, precision=HIGHEST), "model")
 
     def step(da, dv, df, dw, wa, wv, wf, ww, d, b_loc, w_loc, it):
         # squeeze the per-device cell dims
@@ -191,8 +192,8 @@ def make_sharded_step(mesh: Mesh, shards_meta: Dict[str, int],
     specs_in = (cell, cell, cell, cell, cell, cell, cell, cell,
                 P(None, None), yspec, wspec, P())
     specs_out = (P("model"), P())
-    return compat.shard_map(step, mesh=mesh, in_specs=specs_in,
-                            out_specs=specs_out)
+    return jax.shard_map(step, mesh=mesh, in_specs=specs_in,
+                         out_specs=specs_out, check_vma=False)
 
 
 def make_sharded_ops(mesh: Mesh, shards_meta: Dict[str, int]):
@@ -226,25 +227,24 @@ def make_sharded_ops(mesh: Mesh, shards_meta: Dict[str, int]):
         a, v, f, vals = map(sq, (a, v, f, vals))
         y2 = y_loc.reshape(y_loc.shape[-2], y_loc.shape[-1])
         dots = jnp.einsum("ct,ct->c", jnp.take(d, a, axis=0),
-                          jnp.take(y2, v, axis=0))
+                          jnp.take(y2, v, axis=0), precision=HIGHEST)
         w = jax.ops.segment_sum(dots * vals, f, num_segments=nf_l,
                                 indices_are_sorted=True)
         return jax.lax.psum(w, rows)
 
-    dsc_fn = compat.shard_map(
+    dsc_fn = jax.shard_map(
         dsc_op, mesh=mesh,
         in_specs=(cell, cell, cell, cell, P(None, None), P("model")),
-        out_specs=P(rows, None))
-    wc_fn = compat.shard_map(
+        out_specs=P(rows, None), check_vma=False)
+    wc_fn = jax.shard_map(
         wc_op, mesh=mesh,
         in_specs=(cell, cell, cell, cell, P(None, None), P(rows, None)),
-        out_specs=P("model"))
+        out_specs=P("model"), check_vma=False)
     return dsc_fn, wc_fn
 
 
 def make_sharded_sell_ops(mesh: Mesh, shards_meta: Dict[str, int], *,
-                          row_tile: int, slot_tile: int, out_dtype=None,
-                          interpret: bool = True):
+                          slot_tile: int, out_dtype=None):
     """shard_map'd SpMVs over per-cell SELL tiles (the `shard-sell` path).
 
     Same mesh layout and collectives as :func:`make_sharded_ops`, but each
@@ -253,8 +253,12 @@ def make_sharded_sell_ops(mesh: Mesh, shards_meta: Dict[str, int], *,
     ``kernels/wc.py:wc_sell_pallas``) instead of a sorted-COO segment sum —
     the DESIGN.md §7 fast path lifted to mesh granularity (§9).
 
+    The kernels compile when the mesh is made of TPU devices and run in the
+    Pallas interpreter otherwise.
+
     Inputs (global layouts; ``T_p`` = lane-padded Ntheta):
-      cell slot arrays: (R, C, rows_padded, width) sharded (rows, model, ., .)
+      cell slot arrays: (R, C, width, rows_padded) sharded (rows, model, ., .)
+                        — slot-major, from ``kernels/ops.py:sell_lanes``
       d_padded:         (Na, T_p) replicated
       w:                (C*nf_local,) sharded (model,)     [dsc]
       y_padded:         (R*nv_local, T_p) sharded (rows,)  [wc]
@@ -264,7 +268,9 @@ def make_sharded_sell_ops(mesh: Mesh, shards_meta: Dict[str, int], *,
     """
     from repro.kernels import dsc as dsc_kernel
     from repro.kernels import wc as wc_kernel
+    from repro.kernels.ops import interpret_mode
 
+    interpret = interpret_mode(mesh.devices.flat[0].platform)
     rows = _row_axes(mesh)
     nv_l = shards_meta["nv_local"]
     nf_l = shards_meta["nf_local"]
@@ -275,8 +281,8 @@ def make_sharded_sell_ops(mesh: Mesh, shards_meta: Dict[str, int], *,
         a, f, vals = map(sq, (a, f, vals))
         scaled = jnp.take(w_loc.reshape(-1), f) * vals   # padding slots stay 0
         y = dsc_kernel.dsc_sell_pallas(
-            a, scaled, d, row_tile=row_tile, slot_tile=slot_tile,
-            out_dtype=out_dtype, interpret=interpret)
+            a, scaled, d, slot_tile=slot_tile, out_dtype=out_dtype,
+            interpret=interpret)
         return jax.lax.psum(y[:nv_l], "model")
 
     def wc_op(a, v, vals, d, y_loc):
@@ -285,18 +291,18 @@ def make_sharded_sell_ops(mesh: Mesh, shards_meta: Dict[str, int], *,
         # pre-gather of local Y rows; padding slots gather row 0, value 0
         yg = jnp.take(y2, v, axis=0)
         w = wc_kernel.wc_sell_pallas(
-            a, yg, vals, d, row_tile=row_tile, slot_tile=slot_tile,
-            out_dtype=out_dtype, interpret=interpret)
-        return jax.lax.psum(w.reshape(-1)[:nf_l], rows)
+            a, yg, vals, d, slot_tile=slot_tile, out_dtype=out_dtype,
+            interpret=interpret)
+        return jax.lax.psum(w[:nf_l], rows)
 
-    dsc_fn = compat.shard_map(
+    dsc_fn = jax.shard_map(
         dsc_op, mesh=mesh,
         in_specs=(cell, cell, cell, P(None, None), P("model")),
-        out_specs=P(rows, None))
-    wc_fn = compat.shard_map(
+        out_specs=P(rows, None), check_vma=False)
+    wc_fn = jax.shard_map(
         wc_op, mesh=mesh,
         in_specs=(cell, cell, cell, P(None, None), P(rows, None)),
-        out_specs=P("model"))
+        out_specs=P("model"), check_vma=False)
     return dsc_fn, wc_fn
 
 
@@ -328,7 +334,7 @@ def make_sharded_step_1d(mesh: Mesh, shards_meta: Dict[str, int]):
 
     def wc_local(a, v, f, vals, d, y):
         dots = jnp.einsum("ct,ct->c", jnp.take(d, a, axis=0),
-                          jnp.take(y, v, axis=0))
+                          jnp.take(y, v, axis=0), precision=HIGHEST)
         w = jax.ops.segment_sum(dots * vals, f, num_segments=nf,
                                 indices_are_sorted=False)
         return jax.lax.psum(w, all_axes)              # full-w reduction
@@ -342,22 +348,24 @@ def make_sharded_step_1d(mesh: Mesh, shards_meta: Dict[str, int]):
         vv1 = dsc_local(a, v, f, vals, d, gt)
 
         def odd(_):
-            return _safe(jnp.vdot(gt, gt), jnp.vdot(vv1, vv1))
+            return _safe(jnp.vdot(gt, gt, precision=HIGHEST),
+                         jnp.vdot(vv1, vv1, precision=HIGHEST))
 
         def even(_):
             vv2 = projected_gradient(w, wc_local(a, v, f, vals, d, vv1))
-            return _safe(jnp.vdot(vv1, vv1), jnp.vdot(vv2, vv2))
+            return _safe(jnp.vdot(vv1, vv1, precision=HIGHEST),
+                         jnp.vdot(vv2, vv2, precision=HIGHEST))
 
         alpha = jax.lax.cond(it % 2 == 1, odd, even, operand=None)
         w_new = jnp.maximum(w - alpha * gt, 0.0)
-        return w_new, 0.5 * jnp.vdot(y, y)
+        return w_new, 0.5 * jnp.vdot(y, y, precision=HIGHEST)
 
     cell = P(all_axes, None)
-    return compat.shard_map(
+    return jax.shard_map(
         step, mesh=mesh,
         in_specs=(cell, cell, cell, cell, P(None, None), P(None, None),
                   P(None), P()),
-        out_specs=(P(None), P()))
+        out_specs=(P(None), P()), check_vma=False)
 
 
 def life_input_specs_1d(mesh: Mesh, *, n_voxels: int = 247_356,

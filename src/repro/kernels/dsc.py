@@ -5,12 +5,12 @@ Executor for an inspector ``TilePlan`` over voxel-sorted coefficients
 
   * ``D`` (dictionary) is VMEM-resident for the whole grid — the TPU analogue
     of the paper keeping D rows in GPU shared memory.
-  * a coefficient tile contributes ``contrib = D[atoms_t] * scaled_t[:,None]``
-    of shape (C_TILE, Ntheta): the daxpy/BLAS inner loop, vectorized across
-    the 128-lane dimension (Ntheta padded to a lane multiple, mirroring the
-    paper's pad-to-warp-multiple trick).
-  * the voxel scatter becomes a one-hot MXU matmul
-    ``(ROW_TILE x C_TILE) @ (C_TILE x Ntheta)`` into the output row-block —
+  * a coefficient tile holds ``C_TILE`` coefficients on the lane axis.  Its
+    contribution to the output row-block is two MXU matmuls through
+    one-hots (the TPU has no in-VMEM row gather):
+    ``A = (rows_onehot * scaled) @ atoms_onehot^T`` of shape
+    ``(ROW_TILE, Na)`` folds the tile's coefficients per (row, atom), and
+    ``A @ D`` expands them to ``(ROW_TILE, Ntheta)``.  The row one-hot is
     the synchronization-free reduction: the tile plan guarantees a tile
     touches exactly one row-block, and the sequential TPU grid makes
     consecutive-tile accumulation race-free (no atomics exist or are needed).
@@ -21,11 +21,14 @@ tile plan is the inspector, this kernel is the executor.
 
 ``dsc_sell_pallas`` is the SELL fast path (DESIGN.md §7): over the blocked
 ELL layout of ``formats/sell.py`` the tile -> output-block mapping is the
-identity on grid axis 0, so there is **no** scalar prefetch and no one-hot
-matmul — slot ``[r, s]`` belongs to output row ``r`` by construction, and
-the kernel reduces over the slot axis straight into the resident output
-block.  The irregularity the TilePlan machinery handles at run time is paid
-once, as padding, at encode time.
+identity on grid axis 0, so there is **no** scalar prefetch and no row
+one-hot — slot ``[s, r]`` belongs to output row ``r`` by construction.  The
+kernel takes the layout slot-major (slots on sublanes, rows on lanes), so a
+block is ``(slot_tile, LANES)`` for any ``slot_tile`` multiple of 8.
+
+Every matmul runs at ``Precision.HIGHEST``: the one-hot side is exact in
+any precision, but the scaled weights and ``D`` are fp32 and the MXU's
+default pass would round them to bf16.
 """
 from __future__ import annotations
 
@@ -41,6 +44,18 @@ from jax.experimental.pallas import tpu as pltpu
 # tune="off" and the pre-tune call sites get.
 DEFAULT_C_TILE = 256
 DEFAULT_ROW_TILE = 8
+LANES = 128                 # SELL rows per block: the lane width
+
+HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))          # a @ b.T
+_NN = (((1,), (0,)), ((), ()))          # a @ b
+_TN = (((0,), (0,)), ((), ()))          # a.T @ b
+
+
+def onehot_rows(ids: jax.Array, n: int, dtype=jnp.float32) -> jax.Array:
+    """``(n, C)`` one-hot of a ``(1, C)`` lane vector of ids in ``[0, n)``."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, ids.shape[1]), 0)
+            == ids).astype(dtype)
 
 
 def dsc_factory(*, row_tile: int = DEFAULT_ROW_TILE, out_dtype=None,
@@ -53,13 +68,24 @@ def dsc_factory(*, row_tile: int = DEFAULT_ROW_TILE, out_dtype=None,
                              out_dtype=out_dtype, interpret=interpret)
 
 
-def dsc_sell_factory(*, row_tile: int = DEFAULT_ROW_TILE,
-                     slot_tile: int = 32, out_dtype=None,
+def dsc_sell_factory(*, slot_tile: int = 32, out_dtype=None,
                      interpret: bool = False):
     """Bind SELL-DSC launch parameters once (e.g. from a TunePlan)."""
-    return functools.partial(dsc_sell_pallas, row_tile=row_tile,
-                             slot_tile=slot_tile, out_dtype=out_dtype,
-                             interpret=interpret)
+    return functools.partial(dsc_sell_pallas, slot_tile=slot_tile,
+                             out_dtype=out_dtype, interpret=interpret)
+
+
+def fold_tile(weights: jax.Array, atoms: jax.Array,
+              d: jax.Array) -> jax.Array:
+    """``weights (R, C)`` x ``D[atoms] (C, Ntheta)`` without a gather:
+    fold the tile per (row, atom) against the atom one-hot, then expand
+    through ``D`` — both on the MXU."""
+    per_atom = jax.lax.dot_general(
+        weights, onehot_rows(atoms, d.shape[0]), _NT,
+        precision=HIGHEST, preferred_element_type=jnp.float32)  # (R, Na)
+    return jax.lax.dot_general(
+        per_atom, d.astype(jnp.float32), _NN,
+        precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
 def _dsc_kernel(row_block_ref,            # scalar prefetch: (T,) int32
@@ -76,19 +102,12 @@ def _dsc_kernel(row_block_ref,            # scalar prefetch: (T,) int32
     def _():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    atoms = atoms_ref[0]                                    # (C_TILE,)
-    d_rows = d_ref[atoms]                                   # VMEM gather
-    contrib = d_rows * scaled_ref[0][:, None]               # daxpy tile
     row_tile = y_ref.shape[0]
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (row_tile, atoms.shape[0]), 0)
-        == local_row_ref[0][None, :]
-    ).astype(contrib.dtype)
     # segment reduction on the MXU (replaces atomicAdd / warp shuffle)
-    y_ref[...] += jax.lax.dot_general(
-        onehot, contrib, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(y_ref.dtype)
+    weights = (onehot_rows(local_row_ref[...], row_tile)
+               * scaled_ref[...].astype(jnp.float32))       # (R, C)
+    y_ref[...] += fold_tile(weights, atoms_ref[...],
+                            d_ref[...]).astype(y_ref.dtype)
 
 
 def dsc_pallas(row_block: jax.Array, atoms_p: jax.Array, scaled_p: jax.Array,
@@ -97,7 +116,8 @@ def dsc_pallas(row_block: jax.Array, atoms_p: jax.Array, scaled_p: jax.Array,
                interpret: bool = False) -> jax.Array:
     """Run the DSC executor.  Returns (n_row_blocks*row_tile, Ntheta_padded).
 
-    All operands are pre-padded by :mod:`repro.kernels.ops` from a TilePlan.
+    ``atoms_p``/``scaled_p``/``local_row_p`` are ``(n_tiles, c_tile)``
+    tile arrays, pre-padded by :mod:`repro.kernels.ops` from a TilePlan.
     ``out_dtype`` pins the accumulator/output dtype independently of the
     storage dtype of ``dictionary_padded`` (bf16 storage keeps fp32
     accumulation: pass out_dtype=float32).
@@ -105,72 +125,78 @@ def dsc_pallas(row_block: jax.Array, atoms_p: jax.Array, scaled_p: jax.Array,
     n_tiles, c_tile = atoms_p.shape
     n_theta_p = dictionary_padded.shape[1]
     out_dtype = dictionary_padded.dtype if out_dtype is None else out_dtype
+    tile = pl.BlockSpec((None, 1, c_tile), lambda t, rb: (t, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, c_tile), lambda t, rb: (t, 0)),
-            pl.BlockSpec((1, c_tile), lambda t, rb: (t, 0)),
-            pl.BlockSpec((1, c_tile), lambda t, rb: (t, 0)),
+            tile, tile, tile,
             pl.BlockSpec(dictionary_padded.shape, lambda t, rb: (0, 0)),
         ],
         out_specs=pl.BlockSpec((row_tile, n_theta_p), lambda t, rb: (rb[t], 0)),
     )
+    as_tiles = lambda x: x.reshape(n_tiles, 1, c_tile)
     return pl.pallas_call(
         _dsc_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n_row_blocks * row_tile, n_theta_p), out_dtype),
         interpret=interpret,
-    )(row_block, atoms_p, scaled_p, local_row_p, dictionary_padded)
+    )(row_block, as_tiles(atoms_p), as_tiles(scaled_p),
+      as_tiles(local_row_p), dictionary_padded)
 
 
 # ----------------------------------------------------------------------------
-# SELL fast path: direct row-block accumulation, no prefetch, no one-hot.
+# SELL fast path: direct row-block accumulation, no prefetch, no row one-hot.
 # ----------------------------------------------------------------------------
 
-def _dsc_sell_kernel(atoms_ref,           # (ROW_TILE, SLOT_TILE) int32
-                     scaled_ref,          # (ROW_TILE, SLOT_TILE) fp
+def _dsc_sell_kernel(atoms_ref,           # (SLOT_TILE, LANES) int32
+                     scaled_ref,          # (SLOT_TILE, LANES) fp
                      d_ref,               # (Na, Ntheta_p) fp, VMEM-resident
-                     y_ref):              # (ROW_TILE, Ntheta_p) output block
+                     y_ref):              # (LANES, Ntheta_p) output block
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    r, s = atoms_ref.shape
-    d_rows = d_ref[atoms_ref[...].reshape(-1)]              # (R*S, Ntheta_p)
-    contrib = d_rows * scaled_ref[...].reshape(-1)[:, None]  # daxpy slots
-    # slot [r, s] belongs to output row r by layout: reduce the slot axis,
-    # accumulate directly — the one-hot matmul of _dsc_kernel is gone.
-    # cast BEFORE the reduction: with bf16-stored operands the slot-axis sum
-    # must still accumulate in the output dtype (fp32).
-    y_ref[...] += contrib.reshape(r, s, -1).astype(y_ref.dtype).sum(axis=1)
+    n_atoms = d_ref.shape[0]
+    # per_atom_t[a, r] = sum_s scaled[s, r] * (atoms[s, r] == a): slot [s, r]
+    # belongs to output row r by layout, so the slot sum IS the row reduction
+    per_atom_t = jnp.zeros((n_atoms, atoms_ref.shape[1]), jnp.float32)
+    for s in range(atoms_ref.shape[0]):
+        per_atom_t += (onehot_rows(atoms_ref[pl.ds(s, 1), :], n_atoms)
+                       * scaled_ref[pl.ds(s, 1), :].astype(jnp.float32))
+    y_ref[...] += jax.lax.dot_general(
+        per_atom_t, d_ref[...].astype(jnp.float32), _TN,
+        precision=HIGHEST, preferred_element_type=jnp.float32
+    ).astype(y_ref.dtype)
 
 
-def dsc_sell_pallas(atoms: jax.Array, scaled: jax.Array,
-                    dictionary_padded: jax.Array, *, row_tile: int,
-                    slot_tile: int, out_dtype=None,
-                    interpret: bool = False) -> jax.Array:
-    """DSC over a SELL layout.  ``atoms``/``scaled`` are the dense
-    ``(n_rows_padded, width)`` slot arrays of ``formats/sell.py:SellPhi``
-    (``scaled = w[fibers] * values``, padding slots 0).  Returns
-    ``(n_rows_padded, Ntheta_padded)``; grid axis 0 IS the output block
-    index, axis 1 sweeps slot chunks into the resident block."""
-    n_rows_padded, width = atoms.shape
+def dsc_sell_pallas(atoms_t: jax.Array, scaled_t: jax.Array,
+                    dictionary_padded: jax.Array, *, slot_tile: int,
+                    out_dtype=None, interpret: bool = False) -> jax.Array:
+    """DSC over a slot-major SELL layout.
+
+    ``atoms_t``/``scaled_t`` are ``(width, n_rows_padded)``: the transposed
+    slot arrays of ``formats/sell.py:SellPhi`` (``scaled = w[fibers] *
+    values``, padding slots 0) with rows padded to a ``LANES`` multiple
+    (:func:`repro.kernels.ops.sell_lanes`).  Returns ``(n_rows_padded,
+    Ntheta_padded)``; grid axis 0 IS the output block index, axis 1 sweeps
+    slot chunks into the resident block."""
+    width, n_rows_padded = atoms_t.shape
     n_theta_p = dictionary_padded.shape[1]
     out_dtype = dictionary_padded.dtype if out_dtype is None else out_dtype
-    grid = (n_rows_padded // row_tile, width // slot_tile)
+    grid = (n_rows_padded // LANES, width // slot_tile)
+    slots = pl.BlockSpec((slot_tile, LANES), lambda i, j: (j, i))
     return pl.pallas_call(
         _dsc_sell_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((row_tile, slot_tile), lambda i, j: (i, j)),
-            pl.BlockSpec((row_tile, slot_tile), lambda i, j: (i, j)),
+            slots, slots,
             pl.BlockSpec(dictionary_padded.shape, lambda i, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((row_tile, n_theta_p), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((LANES, n_theta_p), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows_padded, n_theta_p), out_dtype),
         interpret=interpret,
-    )(atoms, scaled, dictionary_padded)
+    )(atoms_t, scaled_t, dictionary_padded)

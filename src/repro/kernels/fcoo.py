@@ -8,15 +8,16 @@ spends segment metadata instead of bytes.  Geometry per grid step ``t``:
     (formats/fcoo.py) is loaded; ``D`` stays VMEM-resident as everywhere,
   * the chunk's precomputed segment ranks turn the within-chunk segment
     reduction into a one-hot ``(K, c_tile)`` MXU matmul — the same
-    synchronization-free trick as the COO kernels, but against *chunk-local*
-    segments instead of a planned output row-block,
-  * each step writes its own ``(1, K, .)`` partials block — no cross-step
+    synchronization-free trick (and the same gather-free atom one-hot) as
+    the COO kernels, but against *chunk-local* segments instead of a
+    planned output row-block,
+  * each step writes its own ``(K, .)`` partials block — no cross-step
     accumulation, no scalar prefetch, no ``@pl.when`` zero-init hazard; the
     caller (kernels/ops.py) folds chunk-boundary segments with one batched
     scatter-add over the format's ``seg_rows_*`` map.
 
-bf16 storage keeps fp32 accumulation: products are cast to the output dtype
-before any reduction and the one-hot matmuls pin
+bf16 storage keeps fp32 accumulation: operands are cast to fp32 before any
+reduction and every matmul runs at ``Precision.HIGHEST`` with
 ``preferred_element_type=float32`` (DESIGN.md §10.3).
 """
 from __future__ import annotations
@@ -26,6 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.dsc import fold_tile, onehot_rows
+from repro.kernels.wc import atom_dots, segment_row
 
 
 def fcoo_dsc_factory(*, out_dtype=None, interpret: bool = False):
@@ -45,21 +49,13 @@ def _dsc_fcoo_kernel(atoms_ref,           # (1, C_TILE) int32
                      ranks_ref,           # (1, C_TILE) int32, chunk-local
                      scaled_ref,          # (1, C_TILE) fp (w[fiber] * value)
                      d_ref,               # (Na, Ntheta_p) fp, VMEM-resident
-                     out_ref):            # (1, K, Ntheta_p) segment partials
-    atoms = atoms_ref[0]                                    # (C_TILE,)
-    d_rows = d_ref[atoms]                                   # VMEM gather
-    contrib = d_rows * scaled_ref[0][:, None]               # daxpy chunk
-    k = out_ref.shape[1]
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (k, atoms.shape[0]), 0)
-        == ranks_ref[0][None, :]
-    ).astype(contrib.dtype)
+                     out_ref):            # (K, Ntheta_p) segment partials
     # within-chunk segment reduction on the MXU; the block is exclusively
     # this grid step's, so plain assignment (no accumulation) is race-free
-    out_ref[...] = jax.lax.dot_general(
-        onehot, contrib, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(out_ref.dtype)[None]
+    weights = (onehot_rows(ranks_ref[...], out_ref.shape[0])
+               * scaled_ref[...].astype(jnp.float32))       # (K, C)
+    out_ref[...] = fold_tile(weights, atoms_ref[...],
+                             d_ref[...]).astype(out_ref.dtype)
 
 
 def dsc_fcoo_pallas(atoms: jax.Array, ranks: jax.Array, scaled: jax.Array,
@@ -74,43 +70,33 @@ def dsc_fcoo_pallas(atoms: jax.Array, ranks: jax.Array, scaled: jax.Array,
     n_chunks, c_tile = atoms.shape
     n_theta_p = dictionary_padded.shape[1]
     out_dtype = dictionary_padded.dtype if out_dtype is None else out_dtype
+    chunk = pl.BlockSpec((None, 1, c_tile), lambda t: (t, 0, 0))
+    as_chunks = lambda x: x.reshape(n_chunks, 1, c_tile)
     return pl.pallas_call(
         _dsc_fcoo_kernel,
         grid=(n_chunks,),
         in_specs=[
-            pl.BlockSpec((1, c_tile), lambda t: (t, 0)),
-            pl.BlockSpec((1, c_tile), lambda t: (t, 0)),
-            pl.BlockSpec((1, c_tile), lambda t: (t, 0)),
+            chunk, chunk, chunk,
             pl.BlockSpec(dictionary_padded.shape, lambda t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, seg_k, n_theta_p), lambda t: (t, 0, 0)),
+        out_specs=pl.BlockSpec((None, seg_k, n_theta_p), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_chunks, seg_k, n_theta_p),
                                        out_dtype),
         interpret=interpret,
-    )(atoms, ranks, scaled, dictionary_padded)
+    )(as_chunks(atoms), as_chunks(ranks), as_chunks(scaled),
+      dictionary_padded)
 
 
 def _wc_fcoo_kernel(atoms_ref,            # (1, C_TILE) int32 (WC order)
                     ranks_ref,            # (1, C_TILE) int32, chunk-local
                     vals_ref,             # (1, C_TILE) fp
-                    yg_ref,               # (1, C_TILE, Ntheta_p) fp
+                    yg_ref,               # (C_TILE, Ntheta_p) fp
                     d_ref,                # (Na, Ntheta_p) fp, VMEM-resident
                     out_ref):             # (1, K) segment partials
-    atoms = atoms_ref[0]                                    # (C_TILE,)
-    d_rows = d_ref[atoms]                                   # VMEM gather
-    # cast BEFORE the reductions: bf16-stored operands must still
-    # dot/accumulate in the output dtype (fp32)
-    prods = (d_rows * yg_ref[0]).astype(out_ref.dtype)
-    dots = prods.sum(axis=-1) * vals_ref[0].astype(out_ref.dtype)
-    k = out_ref.shape[1]
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (k, dots.shape[0]), 0)
-        == ranks_ref[0][None, :]
-    ).astype(dots.dtype)
-    out_ref[...] = jax.lax.dot_general(
-        onehot, dots[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(out_ref.dtype).reshape(1, k)
+    dots = (atom_dots(atoms_ref[...], yg_ref[...], d_ref[...])
+            * vals_ref[...].astype(jnp.float32))            # (1, C_TILE)
+    out_ref[...] = segment_row(dots, ranks_ref[...],
+                               out_ref.shape[1]).astype(out_ref.dtype)
 
 
 def wc_fcoo_pallas(atoms: jax.Array, ranks: jax.Array, vals: jax.Array,
@@ -126,17 +112,19 @@ def wc_fcoo_pallas(atoms: jax.Array, ranks: jax.Array, vals: jax.Array,
     n_chunks, c_tile = atoms.shape
     n_theta_p = dictionary_padded.shape[1]
     out_dtype = dictionary_padded.dtype if out_dtype is None else out_dtype
-    return pl.pallas_call(
+    chunk = pl.BlockSpec((None, 1, c_tile), lambda t: (t, 0, 0))
+    as_chunks = lambda x: x.reshape(n_chunks, 1, c_tile)
+    parts = pl.pallas_call(
         _wc_fcoo_kernel,
         grid=(n_chunks,),
         in_specs=[
-            pl.BlockSpec((1, c_tile), lambda t: (t, 0)),
-            pl.BlockSpec((1, c_tile), lambda t: (t, 0)),
-            pl.BlockSpec((1, c_tile), lambda t: (t, 0)),
-            pl.BlockSpec((1, c_tile, n_theta_p), lambda t: (t, 0, 0)),
+            chunk, chunk, chunk,
+            pl.BlockSpec((None, c_tile, n_theta_p), lambda t: (t, 0, 0)),
             pl.BlockSpec(dictionary_padded.shape, lambda t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, seg_k), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, seg_k), out_dtype),
+        out_specs=pl.BlockSpec((None, 1, seg_k), lambda t: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_chunks, 1, seg_k), out_dtype),
         interpret=interpret,
-    )(atoms, ranks, vals, yg, dictionary_padded)
+    )(as_chunks(atoms), as_chunks(ranks), as_chunks(vals), yg,
+      dictionary_padded)
+    return parts.reshape(n_chunks, seg_k)

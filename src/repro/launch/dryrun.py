@@ -103,13 +103,14 @@ def lower_cell(arch: str, shape: str, mesh, *,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = HC.xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     # trip-count-corrected cost model (cost_analysis counts while bodies once)
     hc = HC.analyze(hlo, n_chips)
     mf = RL.model_flops(cfg, shape, seq, batch, kind)
+    # the placeholder devices stand in for a v5e pod
     r = RL.roofline(hc.flops, hc.bytes_accessed, hc.collective_total,
-                    n_chips, mf)
+                    n_chips, mf, device_kind=RL.V5E)
 
     return {
         "status": "ok",
@@ -168,13 +169,14 @@ def _lower_life(mesh, shape: str, variant: str = "2d") -> Dict[str, Any]:
             compiled = lowered.compile()
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = HC.xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hc = HC.analyze(compiled.as_text(), n_chips)
     # useful flops: 2 ops/nnz/theta x (2 DSC + 1.5 WC avg -> here 3 spmv + dots)
     n_theta = meta["n_theta"]
     mf = 3.5 * 2.0 * sc["nnz"] * n_theta
+    # the placeholder devices stand in for a v5e pod
     r = RL.roofline(hc.flops, hc.bytes_accessed, hc.collective_total,
-                    n_chips, mf)
+                    n_chips, mf, device_kind=RL.V5E)
     return {
         "status": "ok", "arch": "life-stn96" + ("-1d" if variant == "1d" else ""),
         "shape": shape, "variant": variant,

@@ -1,6 +1,7 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), TPU v5e constants:
+Three terms per (arch x shape x mesh), from the peaks of the chip the
+program is compiled for (:data:`PEAKS`, keyed by ``device_kind``):
 
   compute    = HLO_FLOPs_global / (chips * 197e12 bf16 FLOP/s)
   memory     = HLO_bytes_global / (chips * 819e9 B/s HBM)
@@ -28,11 +29,25 @@ import math
 import re
 from typing import Any, Dict, Optional
 
-HW = dict(
-    peak_flops=197e12,        # bf16 FLOP/s per v5e chip
-    hbm_bw=819e9,             # B/s per chip
-    link_bw=50e9,             # B/s per ICI link
-)
+#: ``jax.Device.device_kind`` of a TPU v5e chip.
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks keyed by ``device_kind``.  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of interchip interconnect over 4 links (50 GB/s per link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: dict(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of ``device_kind``; a kind not in :data:`PEAKS` has
+    no roofline, so looking it up raises (never a default chip)."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -124,10 +139,11 @@ class Roofline:
 
 def roofline(flops_per_chip: float, bytes_per_chip: float,
              coll_bytes_per_chip: float, n_chips: int,
-             model_flops_global: float) -> Roofline:
-    compute_s = flops_per_chip / HW["peak_flops"]
-    memory_s = bytes_per_chip / HW["hbm_bw"]
-    collective_s = coll_bytes_per_chip / HW["link_bw"]
+             model_flops_global: float, *, device_kind: str) -> Roofline:
+    hw = peaks(device_kind)
+    compute_s = flops_per_chip / hw["peak_flops"]
+    memory_s = bytes_per_chip / hw["hbm_bw"]
+    collective_s = coll_bytes_per_chip / hw["link_bw"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -155,7 +171,7 @@ def model_flops(cfg, shape_name: str, seq: int, batch: int, kind: str) -> float:
 
 
 def mfu_fraction(r: Roofline, n_chips: int, kind: str) -> float:
-    """Achievable model-FLOPs utilization upper bound implied by the terms:
-    useful model flops / (chips * peak * bound-time)."""
-    denom = n_chips * HW["peak_flops"] * max(r.bound_s, 1e-30)
+    """Achievable model-FLOPs utilization upper bound implied by the terms
+    on a v5e: useful model flops / (chips * peak * bound-time)."""
+    denom = n_chips * PEAKS[V5E]["peak_flops"] * max(r.bound_s, 1e-30)
     return r.model_flops / denom

@@ -26,10 +26,6 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-from repro.compat import xla_cost_analysis  # noqa: F401  (re-export: the
-# ground-truth accessor lives beside the cost model; older jax returns a
-# per-partition *list* from Compiled.cost_analysis(), newer a bare dict)
-
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
     "s32": 4, "u32": 4, "s64": 8, "u64": 8, "f8e4m3fn": 1, "f8e5m2": 1,

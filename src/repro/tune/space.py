@@ -19,22 +19,26 @@ from repro.tune.plan import COMPUTE_DTYPES
 #: executor registry name -> the launch-parameter axes its kernels take.
 #: The COO Pallas pair tiles coefficients (c_tile) into row blocks
 #: (row_tile); the SELL kernels and their per-cell shard variants walk
-#: (row_tile, slot_tile) blocks of the slot layout; the F-COO pair chunks
-#: the linearized stream (c_tile) with seg_tile-quantized segment blocks.
+#: (slot_tile, 128-row) blocks of the slot-major layout, so their row block
+#: is the lane width and not an axis; the F-COO pair chunks the linearized
+#: stream (c_tile) with seg_tile-quantized segment blocks.
 TUNABLE_TILES: Dict[str, Tuple[str, ...]] = {
     "kernel": ("c_tile", "row_tile"),
-    "kernel-sell": ("row_tile", "slot_tile"),
+    "kernel-sell": ("slot_tile",),
     "kernel-fcoo": ("c_tile", "seg_tile"),
-    "shard-sell": ("row_tile", "slot_tile"),
+    "shard-sell": ("slot_tile",),
 }
 
 #: per-axis candidate values (the current config value is always added).
-#: row_tile stays a multiple of the fp32 sublane (8); slot_tile and c_tile
-#: sweep the padding-vs-occupancy trade-off the paper's Table 9 measures.
+#: Every value compiles for the TPU: row_tile (the COO output row-block)
+#: and slot_tile (SELL slots, which the kernels take on the sublane axis)
+#: stay multiples of the fp32 sublane (8); c_tile and seg_tile size lane
+#: blocks that span the whole array axis.  slot_tile and c_tile sweep the
+#: padding-vs-occupancy trade-off the paper's Table 9 measures.
 AXIS_CANDIDATES: Dict[str, Tuple[int, ...]] = {
     "c_tile": (128, 256, 512),
     "row_tile": (8, 16),
-    "slot_tile": (16, 32, 64),
+    "slot_tile": (8, 16, 32),
     "seg_tile": (8, 16, 32),
 }
 

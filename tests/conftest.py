@@ -5,13 +5,6 @@ import sys
 # launch/dryrun.py forces 512 placeholder devices.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:                                   # declared dev dependency; containers
-    import hypothesis                  # without it fall back to the
-except ImportError:                    # deterministic stub
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-    _hypothesis_stub.install(sys.modules)
-
 import jax
 import numpy as np
 import pytest

@@ -35,8 +35,9 @@ def test_sharded_sbbnnls_matches_single_device():
 
         p = synth_connectome(n_fibers=96, n_theta=16, n_atoms=24,
                              grid=(10,10,10), seed=3)
-        from repro import compat
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         shards = LS.build_life_shards(p.phi, 16, R=4, C=2)
         step = LS.make_sharded_step(mesh, dict(nv_local=shards.nv_local,
                                                nf_local=shards.nf_local,
@@ -78,9 +79,10 @@ def test_train_step_on_mesh_and_elastic_restart():
         opt = OptConfig(lr=3e-3)          # 8 total steps must visibly descend
         data = DataConfig(seed=0, seq_len=32, global_batch=8)
 
-        from repro import compat
+        from jax.sharding import AxisType
         def build(mesh_shape):
-            mesh = compat.make_mesh(mesh_shape, ("data", "model"))
+            mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
             hints.activate(mesh)
             pspecs = lambda tree: SH.logical_to_shardings(
                 mesh, SH.param_specs(cfg, mesh, tree))
@@ -135,8 +137,9 @@ def test_moe_ep_train_step_on_mesh():
         cfg = dataclasses.replace(reduced(get_config("phi3.5-moe-42b-a6.6b")),
                                   remat=False)
         opt = OptConfig(lr=1e-3)
-        from repro import compat
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         hints.activate(mesh)
         params, opt_state = ST.init_all(cfg, opt, jax.random.PRNGKey(0))
         step_fn = jax.jit(ST.make_train_step(cfg, opt))

@@ -1,9 +1,7 @@
 """F-COO linearization + segment-scan primitive: property suite
 (DESIGN.md §11; structure mirrors test_shard_format.py).
 
-Property tests run through the hypothesis stub when the real package is
-missing (tests/_hypothesis_stub.py), so they execute everywhere.  The
-pure-jnp references exercise the layout's semantics; the Pallas kernel
+The pure-jnp references exercise the layout's semantics; the Pallas kernel
 pair itself is additionally held to the dense oracle by the conformance
 matrix (test_conformance.py) the moment ``kernel-fcoo`` registers.
 """

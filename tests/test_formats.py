@@ -1,8 +1,4 @@
-"""Sparse-format subsystem: round-trips, SELL kernels vs oracle, selection.
-
-Property tests run through the hypothesis stub when the real package is
-missing (tests/_hypothesis_stub.py), so they execute everywhere.
-"""
+"""Sparse-format subsystem: round-trips, SELL kernels vs oracle, selection."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -141,14 +137,12 @@ def test_sell_kernel_executor_matches_dense(tiny_problem, tiny_dense, rng):
     from repro.kernels import ops as kops
     p = tiny_problem
     w = jnp.asarray(rng.uniform(size=p.phi.n_fibers), jnp.float32)
-    mv = kops.make_dsc_sell(SellPhi.encode(p.phi, op="dsc"), p.dictionary,
-                            interpret=True)
+    mv = kops.make_dsc_sell(SellPhi.encode(p.phi, op="dsc"), p.dictionary)
     np.testing.assert_allclose(
         np.asarray(mv(w)).reshape(-1), np.asarray(tiny_dense @ w),
         rtol=2e-4, atol=2e-4)
     y = jnp.asarray(rng.normal(size=(p.phi.n_voxels, 16)), jnp.float32)
-    rv = kops.make_wc_sell(SellPhi.encode(p.phi, op="wc"), p.dictionary,
-                           interpret=True)
+    rv = kops.make_wc_sell(SellPhi.encode(p.phi, op="wc"), p.dictionary)
     np.testing.assert_allclose(
         np.asarray(rv(y)), np.asarray(tiny_dense.T @ y.reshape(-1)),
         rtol=2e-4, atol=2e-4)
@@ -165,8 +159,8 @@ def test_property_sell_kernels(case):
     d = make_dictionary(phi.n_atoms, 8)
     w = jnp.asarray(r.uniform(size=phi.n_fibers), jnp.float32)
     y = jnp.asarray(r.normal(size=(phi.n_voxels, 8)), jnp.float32)
-    mv = kops.make_dsc_sell(SellPhi.encode(phi, op="dsc"), d, interpret=True)
-    rv = kops.make_wc_sell(SellPhi.encode(phi, op="wc"), d, interpret=True)
+    mv = kops.make_dsc_sell(SellPhi.encode(phi, op="dsc"), d)
+    rv = kops.make_wc_sell(SellPhi.encode(phi, op="wc"), d)
     np.testing.assert_allclose(np.asarray(mv(w)),
                                np.asarray(spmv.dsc_naive(phi, d, w)),
                                rtol=1e-3, atol=1e-3)

@@ -28,7 +28,7 @@ def test_scan_flops_corrected():
         return y
 
     c = jax.jit(scanned).lower(x, ws).compile()
-    xla = hlo_cost.xla_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     hc = hlo_cost.analyze(c.as_text(), 1)
     expected = 8 * 2 * 128 ** 3
     assert xla < expected / 4                   # XLA undercounts
@@ -47,7 +47,7 @@ def test_matches_xla_on_unrolled_grad():
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     c = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, ws).compile()
-    ca = hlo_cost.xla_cost_analysis(c)
+    ca = c.cost_analysis()
     hc = hlo_cost.analyze(c.as_text(), 1)
     assert 0.8 <= hc.flops / ca["flops"] <= 1.05       # dots only
     np.testing.assert_allclose(hc.bytes_accessed_xla, ca["bytes accessed"],
@@ -70,7 +70,7 @@ def test_scan_equals_unrolled_through_cost_model():
     c_scan = jax.jit(jax.grad(f_scan, argnums=(0, 1))).lower(x, ws).compile()
     c_un = jax.jit(jax.grad(f_unroll, argnums=(0, 1))).lower(x, ws).compile()
     hc = hlo_cost.analyze(c_scan.as_text(), 1)
-    xla_unrolled = hlo_cost.xla_cost_analysis(c_un)["flops"]
+    xla_unrolled = c_un.cost_analysis()["flops"]
     np.testing.assert_allclose(hc.flops, xla_unrolled, rtol=0.15)
 
 
@@ -84,18 +84,18 @@ def test_collective_parse_on_psum():
         import sys; sys.path.insert(0, %r)
         import jax, jax.numpy as jnp, json
         from jax.sharding import PartitionSpec as P
-        from repro import compat
+        from jax.sharding import AxisType
         from repro.roofline import hlo_cost
-        mesh = compat.make_mesh((8,), ("d",))
+        mesh = jax.make_mesh((8,), ("d",), axis_types=(AxisType.Auto,))
         def body(x, w):
             y = jax.lax.psum(x @ w, "d")          # (16, 64) all-reduce
             i = jax.lax.axis_index("d")
             return jax.lax.dynamic_slice(y, (0, i * 8), (16, 8)), None
         def f(x, ws):
             return jax.lax.scan(body, x, ws)[0]
-        sm = compat.shard_map(f, mesh=mesh,
-                              in_specs=(P(None, "d"), P(None, "d", None)),
-                              out_specs=P(None, None))
+        sm = jax.shard_map(f, mesh=mesh,
+                           in_specs=(P(None, "d"), P(None, "d", None)),
+                           out_specs=P(None, None), check_vma=False)
         x = jax.ShapeDtypeStruct((16, 64), jnp.float32)
         ws = jax.ShapeDtypeStruct((8, 64, 64), jnp.float32)
         with mesh:
@@ -116,11 +116,25 @@ def test_collective_parse_on_psum():
 
 
 def test_roofline_terms_and_dominance():
+    from repro.roofline.analysis import V5E
     r = roofline(flops_per_chip=1.97e14, bytes_per_chip=819e9,
                  coll_bytes_per_chip=100e9, n_chips=4,
-                 model_flops_global=4 * 1.97e14 * 0.5)
+                 model_flops_global=4 * 1.97e14 * 0.5, device_kind=V5E)
     assert np.isclose(r.compute_s, 1.0)
     assert np.isclose(r.memory_s, 1.0)
     assert np.isclose(r.collective_s, 2.0)
     assert r.dominant == "collective"
     assert np.isclose(r.useful_ratio, 0.5)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """The v5e has published peaks; any other kind (the CPU included) has
+    no roofline, and asking for one raises instead of defaulting."""
+    from repro.roofline.analysis import V5E, peaks
+    assert peaks(V5E)["hbm_bw"] == 819e9
+    r = roofline(197e12, 0.0, 0.0, 1, 197e12, device_kind=V5E)
+    assert r.dominant == "compute" and r.bound_s == pytest.approx(1.0)
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks(jax.devices("cpu")[0].device_kind)
+    with pytest.raises(KeyError):
+        roofline(1.0, 1.0, 0.0, 1, 1.0, device_kind="cpu")

@@ -41,7 +41,7 @@ def test_dsc_kernel_shapes(nc, nv, nf, c_tile, row_tile, n_theta):
     phi_v, _ = sort_by_host(phi, "voxel")
     plan = plan_tiles(np.asarray(phi_v.voxels), nv, c_tile=c_tile,
                       row_tile=row_tile)
-    mv = kops.make_dsc(phi_v, d, plan, interpret=True)
+    mv = kops.make_dsc(phi_v, d, plan)
     want = spmv.dsc_naive(phi, d, w)
     np.testing.assert_allclose(np.asarray(mv(w)), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -59,7 +59,7 @@ def test_wc_kernel_shapes(nc, nv, nf, c_tile, row_tile):
     phi_f, _ = sort_by_host(phi, "fiber")
     plan = plan_tiles(np.asarray(phi_f.fibers), nf, c_tile=c_tile,
                       row_tile=row_tile)
-    rv = kops.make_wc(phi_f, d, plan, interpret=True)
+    rv = kops.make_wc(phi_f, d, plan)
     want = spmv.wc_naive(phi, d, y)
     np.testing.assert_allclose(np.asarray(rv(y)), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -73,7 +73,7 @@ def test_dsc_kernel_dtypes(dtype):
     w = jnp.asarray(np.random.default_rng(1).uniform(size=40), dtype)
     phi_v, _ = sort_by_host(phi, "voxel")
     plan = plan_tiles(np.asarray(phi_v.voxels), 50, c_tile=64, row_tile=8)
-    mv = kops.make_dsc(phi_v, d, plan, interpret=True)
+    mv = kops.make_dsc(phi_v, d, plan)
     want = spmv.dsc_naive(phi.astype(jnp.float32),
                           d.astype(jnp.float32), w.astype(jnp.float32))
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-4
@@ -90,7 +90,7 @@ def test_property_dsc_kernel(nc, nv, nf, seed):
     w = jnp.asarray(np.random.default_rng(seed).uniform(size=nf), jnp.float32)
     phi_v, _ = sort_by_host(phi, "voxel")
     plan = plan_tiles(np.asarray(phi_v.voxels), nv, c_tile=32, row_tile=8)
-    mv = kops.make_dsc(phi_v, d, plan, interpret=True)
+    mv = kops.make_dsc(phi_v, d, plan)
     np.testing.assert_allclose(
         np.asarray(mv(w)), np.asarray(spmv.dsc_naive(phi, d, w)),
         rtol=1e-3, atol=1e-3)
@@ -105,8 +105,8 @@ def test_kernel_on_synthetic_connectome(tiny_problem):
                           c_tile=128, row_tile=8)
     wc_plan = plan_tiles(np.asarray(phi_f.fibers), p.phi.n_fibers,
                          c_tile=128, row_tile=8)
-    mv = kops.make_dsc(phi_v, p.dictionary, dsc_plan, interpret=True)
-    rv = kops.make_wc(phi_f, p.dictionary, wc_plan, interpret=True)
+    mv = kops.make_dsc(phi_v, p.dictionary, dsc_plan)
+    rv = kops.make_wc(phi_f, p.dictionary, wc_plan)
     w = jnp.ones((p.phi.n_fibers,), jnp.float32)
     y = mv(w)
     np.testing.assert_allclose(

@@ -223,7 +223,7 @@ def test_predicted_tune_plan_zero_measurements(tmp_path, tiny_problem,
     plan = eng.tune_plan
     assert plan is not None and plan.reason == "predicted"
     assert plan.executor == "kernel-sell"
-    assert set(plan.params) == {"row_tile", "slot_tile"}
+    assert set(plan.params) == {"slot_tile"}
     assert plan.compute_dtype in ("fp32", "bf16")       # resolved, not auto
     # predicted plans are persisted: a second cached build replays it
     eng2 = LifeEngine(tiny_problem, dataclasses.replace(cfg))

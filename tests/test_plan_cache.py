@@ -234,3 +234,27 @@ def test_shard_plan_mesh_shape_mismatch_is_clean_miss(tmp_path,
     # and the original topology still hits its own entry
     partition_cuts(tiny_problem.phi, 4, 2, cache=cache)
     assert cache.stats.hits == 1
+
+
+@pytest.mark.parametrize("env", [None, "/some/shared/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env):
+    """$JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache is
+    the fixed <checkout>/.jax_cache (no temp dir, pid or time in it)."""
+    import jax
+    from pathlib import Path
+    from repro import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    path = compile_cache.enable_compile_cache()
+    if env is None:
+        checkout = Path(__file__).resolve().parents[1]
+        assert path == str(checkout / ".jax_cache")
+        assert updates == [("jax_compilation_cache_dir", path)]
+    else:
+        assert path == env and updates == []

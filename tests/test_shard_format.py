@@ -1,9 +1,7 @@
 """ShardPhi partitioned layout: round-trips, partition invariants, inert
 padding (DESIGN.md §9).
 
-Property tests run through the hypothesis stub when the real package is
-missing (tests/_hypothesis_stub.py), so they execute everywhere.  The
-pure-numpy references over the stacked cell arrays are what lets multi-cell
+The pure-numpy references over the stacked cell arrays are what lets multi-cell
 layouts (R*C > 1) be exercised in a single-device test process — the
 shard_map executors themselves are covered by test_conformance.py.
 """

@@ -99,7 +99,7 @@ def test_search_space_keeps_default_under_budget():
     for budget in (2, 4, 6):
         cands = search_space("kernel-sell", _CFG, budget=budget)
         assert len(cands) <= max(budget, 1)
-        assert cands[0] == dict(params=dict(row_tile=8, slot_tile=16),
+        assert cands[0] == dict(params=dict(slot_tile=16),
                                 compute_dtype="fp32")
 
 
@@ -187,7 +187,7 @@ def test_cached_miss_uses_defaults_without_measuring(tmp_path, tiny_problem,
     eng = LifeEngine(tiny_problem, cfg)
     plan = eng.tune_plan
     assert plan.reason == "untuned"
-    assert plan.params == dict(row_tile=8, slot_tile=16)
+    assert plan.params == dict(slot_tile=16)
     # the miss persisted nothing: a later "cached" engine still misses
     eng2 = LifeEngine(tiny_problem, cfg)
     assert eng2.tune_plan.reason == "untuned"
