@@ -105,7 +105,10 @@ class BatchedLifeEngine:
     """
 
     def __init__(self, problems: Sequence[LifeProblem], config,
-                 cache: Optional[PlanCache] = None):
+                 cache: Optional[PlanCache] = None, *,
+                 jobs: Sequence[str] = ()):
+        """``jobs`` names the service jobs this engine solves; it only
+        labels the engine's spans (DESIGN.md §12.4)."""
         if not problems:
             raise ValueError("need at least one subject")
         self.problems = list(problems)
@@ -133,8 +136,12 @@ class BatchedLifeEngine:
         self.dictionary = p0.dictionary
         self.n_subjects = len(self.problems)
         self.inspector_seconds = 0.0
+        self.jobs = tuple(jobs)
         self.mesh = self._make_mesh()
-        self._build()
+        with obs.span("engine.build", {
+                "engine": "batched", "jobs": self.jobs,
+                "nc": sum(p.phi.n_coeffs for p in self.problems)}):
+            self._build()
 
     def _make_mesh(self):
         """(data, model) mesh when the config asks for a multi-cell layout."""
@@ -307,7 +314,8 @@ class BatchedLifeEngine:
                                        states, n_iters=k)
             return new, np.asarray(losses)
         with obs.span("engine.step", {"executor": self.config.executor,
-                                      "batched": self.n_subjects, "k": k}):
+                                      "batched": self.n_subjects, "k": k,
+                                      "jobs": self.jobs}):
             t0 = time.perf_counter()
             new, losses = self._runner(self.phi_dsc, self.phi_wc, self.b,
                                        states, n_iters=k)

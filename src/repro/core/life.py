@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,7 +115,10 @@ class LifeEngine:
     """Binds a LifeProblem to an executor; runs SBBNNLS; reports pruning."""
 
     def __init__(self, problem: LifeProblem, config: LifeConfig,
-                 cache: Optional[PlanCache] = None):
+                 cache: Optional[PlanCache] = None, *,
+                 jobs: Sequence[str] = ()):
+        """``jobs`` names the service jobs this engine solves; it only
+        labels the engine's spans (DESIGN.md §12.4)."""
         if config.executor not in REGISTRY:
             raise ValueError(f"executor must be one of {REGISTRY.names()}")
         from repro.tune.tuner import validate_config as _validate_tune
@@ -125,44 +128,40 @@ class LifeEngine:
         self.cache = cache if cache is not None else PlanCache(
             config.plan_cache_dir, config.plan_cache_max_bytes)
         self.inspector_seconds = 0.0
+        self.jobs = tuple(jobs)
         self._build(problem.phi)
 
     # -- inspector ----------------------------------------------------------
     def _build(self, phi: PhiTensor) -> None:
-        t0 = time.perf_counter()
-        self.phi = phi
-        if self.config.format == "coo":
-            name = self.config.executor
-            if self.config.shard_rows * self.config.shard_cols > 1:
-                # a multi-cell mesh request is the strongest signal: route
-                # through the mesh-aware mapping (-> "shard") instead of
-                # silently running the configured executor on one device
-                from repro.formats import select as fsel
-                name = fsel.executor_for("coo", self.config)
-            self.executor: Executor = REGISTRY.create(
-                name, phi, self.problem, self.config, self.cache)
-        else:
-            # format-parameterized path: "sell"/"alto" force that layout's
-            # executor; "auto" selects per dataset (FormatPlan-cached)
-            self.executor = create_for_format(
-                phi, self.problem, self.config, self.cache)
-        self.matvec = self.executor.matvec
-        self.rmatvec = self.executor.rmatvec
-        dt = time.perf_counter() - t0
+        with obs.span("engine.build", {"engine": "single", "jobs": self.jobs,
+                                       "nc": phi.n_coeffs}):
+            t0 = time.perf_counter()
+            self.phi = phi
+            if self.config.format == "coo":
+                name = self.config.executor
+                if self.config.shard_rows * self.config.shard_cols > 1:
+                    # a multi-cell mesh request is the strongest signal:
+                    # route through the mesh-aware mapping (-> "shard")
+                    # instead of silently running the configured executor
+                    # on one device
+                    from repro.formats import select as fsel
+                    name = fsel.executor_for("coo", self.config)
+                self.executor: Executor = REGISTRY.create(
+                    name, phi, self.problem, self.config, self.cache)
+            else:
+                # format-parameterized path: "sell"/"alto" force that
+                # layout's executor; "auto" selects per dataset
+                # (FormatPlan-cached)
+                self.executor = create_for_format(
+                    phi, self.problem, self.config, self.cache)
+            self.matvec = self.executor.matvec
+            self.rmatvec = self.executor.rmatvec
+            dt = time.perf_counter() - t0
         self.inspector_seconds += dt
         obs.histogram("engine.build.seconds").observe(dt)
-        # held instruments for the hot step loop (no-ops while disabled);
-        # HLO byte counts are invalidated here because compaction rebinds
-        # the SpMV closures over a smaller Phi
-        self._op_bytes: Optional[float] = None
+        # held instrument for the hot step loop (a no-op while disabled)
         self._h_step = obs.histogram("engine.step.seconds",
                                      executor=self.executor.name)
-        self._g_frac = obs.gauge("engine.roofline.fraction",
-                                 executor=self.executor.name,
-                                 format=self.config.format)
-        self._g_bw = obs.gauge("engine.achieved_bandwidth.gbps",
-                               executor=self.executor.name,
-                               format=self.config.format)
 
     @property
     def dsc_plan(self):
@@ -221,55 +220,13 @@ class LifeEngine:
             return new, np.asarray(ls)
         with obs.span("engine.step", {"executor": self.executor.name,
                                       "format": self.config.format,
-                                      "k": k}) as sp:
+                                      "k": k, "jobs": self.jobs}):
             t0 = time.perf_counter()
             new, ls = sbbnnls_steps(self.matvec, self.rmatvec,
                                     self.problem.b, state, k)
             ls = np.asarray(ls)     # host transfer blocks on the computation
-            dt = time.perf_counter() - t0
-            self._h_step.observe(dt)
-            self._annotate_roofline(sp, k, dt)
+            self._h_step.observe(time.perf_counter() - t0)
         return new, ls
-
-    def _annotate_roofline(self, sp, k: int, dt: float) -> None:
-        """Set achieved-bandwidth gauges from HLO byte counts (obs-on only).
-
-        Bytes per SBBNNLS iteration follow the tuner's dominant-op mix
-        (DSC every iteration + line-search probe, WC on alternation):
-        ``DSC_WEIGHT * dsc_bytes + WC_WEIGHT * wc_bytes``.  Fraction is
-        against the running chip's HBM bandwidth (``analysis.PEAKS``); on a
-        device with no published peaks (a CPU) nothing is written."""
-        from repro.roofline.analysis import PEAKS
-        kind = jax.devices()[0].device_kind
-        if kind not in PEAKS or dt <= 0.0:
-            return
-        bytes_per_iter = self._op_bytes_per_iter()
-        achieved = bytes_per_iter * k / dt
-        frac = achieved / PEAKS[kind]["hbm_bw"]
-        self._g_bw.set(achieved / 1e9)
-        self._g_frac.set(frac)
-        sp.set_attr("bytes_accessed", bytes_per_iter * k)
-        sp.set_attr("achieved_gbps", achieved / 1e9)
-        sp.set_attr("roofline_fraction", frac)
-
-    def _op_bytes_per_iter(self) -> float:
-        """Weighted HBM bytes of one SBBNNLS iteration, from the compiled
-        HLO of the bound SpMV pair (lazy, memoized until the next _build)."""
-        if self._op_bytes is None:
-            from repro.roofline import hlo_cost
-            from repro.tune.tuner import DSC_WEIGHT, WC_WEIGHT
-            d = self.problem.dictionary
-            probes = ((self.matvec, jnp.ones((self.phi.n_fibers,), d.dtype)),
-                      (self.rmatvec,
-                       jnp.ones((self.phi.n_voxels, d.shape[1]), d.dtype)))
-            apply = jax.jit(lambda fn, x: fn(x))
-            dsc_b, wc_b = (
-                hlo_cost.analyze(
-                    apply.lower(fn, probe).compile().as_text(),
-                    n_chips=1).bytes_accessed
-                for fn, probe in probes)
-            self._op_bytes = DSC_WEIGHT * dsc_b + WC_WEIGHT * wc_b
-        return self._op_bytes
 
     def run(self, n_iters: Optional[int] = None,
             w0: Optional[jax.Array] = None) -> Tuple[jax.Array, np.ndarray]:

@@ -45,24 +45,38 @@ def projected_gradient(w: Array, g: Array) -> Array:
 
 def sbbnnls_step(matvec: MatVec, rmatvec: MatVec, b: Array,
                  state: SbbnnlsState) -> SbbnnlsState:
-    """One SBBNNLS iteration (Algorithm 1)."""
+    """One SBBNNLS iteration (Algorithm 1).
+
+    The SpMVs run under the named scopes ``sbbnnls.dsc`` and
+    ``sbbnnls.wc``, the projection, step size and update under
+    ``sbbnnls.bb``, so each op's metadata (and a profiler trace's
+    ``tf_op``) says which part of the iteration it belongs to.  The
+    scopes do not nest and change no computation."""
     w, it = state.w, state.it
-    y = matvec(w) - b                       # DSC (+ residual)
-    g = rmatvec(y)                          # WC
-    gt = projected_gradient(w, g)
-    v = matvec(gt)                          # DSC
+    with jax.named_scope("sbbnnls.dsc"):
+        y = matvec(w) - b                   # DSC (+ residual)
+    with jax.named_scope("sbbnnls.wc"):
+        g = rmatvec(y)                      # WC
+    with jax.named_scope("sbbnnls.bb"):
+        gt = projected_gradient(w, g)
+    with jax.named_scope("sbbnnls.dsc"):
+        v = matvec(gt)                      # DSC
 
     def odd_alpha(_):
-        return _safe_div(_dot(gt, gt), _dot(v, v))
+        with jax.named_scope("sbbnnls.bb"):
+            return _safe_div(_dot(gt, gt), _dot(v, v))
 
     def even_alpha(_):
-        vv = rmatvec(v)                     # WC (every other iteration)
-        vv = projected_gradient(w, vv)
-        return _safe_div(_dot(v, v), _dot(vv, vv))
+        with jax.named_scope("sbbnnls.wc"):
+            vv = rmatvec(v)                 # WC (every other iteration)
+        with jax.named_scope("sbbnnls.bb"):
+            vv = projected_gradient(w, vv)
+            return _safe_div(_dot(v, v), _dot(vv, vv))
 
     alpha = jax.lax.cond(it % 2 == 1, odd_alpha, even_alpha, operand=None)
-    w_new = jnp.maximum(w - alpha * gt, 0.0)
-    loss = 0.5 * _dot(y, y)
+    with jax.named_scope("sbbnnls.bb"):
+        w_new = jnp.maximum(w - alpha * gt, 0.0)
+        loss = 0.5 * _dot(y, y)
     return SbbnnlsState(w=w_new, it=it + 1, loss=loss)
 
 

@@ -2,12 +2,19 @@
 
 A span is a named, attributed interval on the monotonic clock
 (``time.monotonic_ns`` — wall-clock jumps can never produce negative
-durations).  Nesting follows ``with`` structure: the tracer keeps an open
-stack, a span entered while another is open becomes its child, and the
-roots form the trace.  The taxonomy the repo emits is documented in
-DESIGN.md §12.4 (``scheduler.tick`` > ``scheduler.slice`` >
-``engine.step``; ``service.checkpoint``; ``tune.search``;
-``engine.build``).
+durations).  Nesting follows ``with`` structure within a thread: each
+thread keeps its own open stack, a span entered while another span of the
+same thread is open becomes its child, and the roots form the trace.  So
+a client thread's span never nests under another thread's.  The
+taxonomy the repo emits is documented in DESIGN.md §12.4.
+
+Profiler clock: while it is open, an enabled span also holds a
+``jax.profiler.TraceAnnotation`` of its name with its attributes as
+stats, so a ``jax.profiler`` trace shows it on the host plane beside the
+device's ops, on the same clock.  Sequence attributes (``jobs``) go to the
+profiler as one space-separated string.  Attributes set inside the span
+(``set_attr``) reach the in-memory tree only.  JAX is imported on the
+first enabled span, not at import time.
 
 Disabled-path contract: ``Tracer.span()`` returns a shared no-op context
 manager when the switch is off — no span object is allocated, entering
@@ -28,6 +35,7 @@ grow a trace forever).
 from __future__ import annotations
 
 import json
+import threading
 import time
 from typing import Dict, List, Optional
 
@@ -37,8 +45,8 @@ from repro.obs.runtime import SWITCH
 class Span:
     """One timed interval; a context manager bound to its tracer."""
 
-    __slots__ = ("name", "attrs", "start_ns", "end_ns", "children",
-                 "_tracer")
+    __slots__ = ("name", "attrs", "start_ns", "end_ns", "children", "tid",
+                 "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[Dict[str, object]] = None):
@@ -47,10 +55,12 @@ class Span:
         self.start_ns = 0
         self.end_ns = 0
         self.children: List[Span] = []
+        self.tid = 0
         self._tracer = tracer
+        self._annotation = None
 
     def set_attr(self, key: str, value: object) -> None:
-        """Attach a result computed inside the span (e.g. achieved GB/s)."""
+        """Attach a result computed inside the span (e.g. a chosen format)."""
         self.attrs[key] = value
 
     @property
@@ -58,12 +68,19 @@ class Span:
         return (self.end_ns - self.start_ns) / 1e9
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation
+        self.tid = threading.get_ident()
         self._tracer._push(self)
+        self._annotation = TraceAnnotation(
+            self.name, **{k: _stat(v) for k, v in self.attrs.items()})
+        self._annotation.__enter__()
         self.start_ns = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         self.end_ns = time.monotonic_ns()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
         self._tracer._pop(self)
 
     def as_dict(self) -> dict:
@@ -71,6 +88,14 @@ class Span:
                     start_us=self.start_ns / 1e3,
                     dur_us=(self.end_ns - self.start_ns) / 1e3,
                     children=[c.as_dict() for c in self.children])
+
+
+def _stat(value: object) -> object:
+    """A span attribute as a profiler stat: sequences joined by spaces
+    (the profiler's own encoding separates attributes by commas)."""
+    if isinstance(value, (list, tuple)):
+        return " ".join(str(v) for v in value)
+    return value
 
 
 class _NoopSpan:
@@ -92,13 +117,15 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class Tracer:
-    """Span factory + the open-span stack + the finished-span forest."""
+    """Span factory + one open-span stack per thread + the finished-span
+    forest."""
 
     def __init__(self, max_spans: int = 100_000):
         self.roots: List[Span] = []
         self.dropped = 0
         self.max_spans = max_spans
-        self._stack: List[Span] = []
+        self._local = threading.local()
+        self._lock = threading.Lock()
         self._recorded = 0
 
     def span(self, name: str,
@@ -112,24 +139,33 @@ class Tracer:
         return Span(self, name, attrs)
 
     # -- stack maintenance (called by Span.__enter__/__exit__) -------------
+    def _stack(self) -> List[Span]:
+        """The calling thread's open spans, outermost first."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _push(self, span: Span) -> None:
-        self._stack.append(span)
+        self._stack().append(span)
 
     def _pop(self, span: Span) -> None:
+        stack = self._stack()
         # tolerate interleaved exits (generators, exceptions): unwind to
         # the span being closed rather than assuming strict LIFO
-        while self._stack:
-            top = self._stack.pop()
+        while stack:
+            top = stack.pop()
             if top is span:
                 break
-        if self._recorded >= self.max_spans:
-            self.dropped += 1
-            return
-        if self._stack:
-            self._stack[-1].children.append(span)
-        else:
-            self.roots.append(span)
-        self._recorded += 1
+        with self._lock:
+            if self._recorded >= self.max_spans:
+                self.dropped += 1
+                return
+            if stack:
+                stack[-1].children.append(span)
+            else:
+                self.roots.append(span)
+            self._recorded += 1
 
     # -- export ------------------------------------------------------------
     def export(self) -> List[dict]:
@@ -142,7 +178,7 @@ class Tracer:
 
         def walk(span: Span) -> None:
             events.append(dict(
-                name=span.name, ph="X", pid=0, tid=0,
+                name=span.name, ph="X", pid=0, tid=span.tid,
                 ts=span.start_ns / 1e3,
                 dur=(span.end_ns - span.start_ns) / 1e3,
                 args=dict(span.attrs)))
@@ -158,6 +194,6 @@ class Tracer:
 
     def reset(self) -> None:
         self.roots.clear()
-        self._stack.clear()
+        self._local = threading.local()       # every thread's open stack
         self.dropped = 0
         self._recorded = 0

@@ -25,6 +25,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.std import PhiTensor
 from repro.data.dmri import LifeProblem
 from repro.science.crossval import heldout_rmse, restrict_to_voxels
@@ -57,9 +58,10 @@ def lesion_problem(problem: LifeProblem,
         raise ValueError(f"fiber ids must be in [0, {problem.phi.n_fibers}),"
                          f" got range [{ids[0]}, {ids[-1]}]")
     phi = problem.phi
-    fib = np.asarray(phi.fibers, np.int64)
-    keep = np.nonzero(~np.isin(fib, ids))[0]
-    sub = phi.take(jnp.asarray(keep, jnp.int32))
+    with obs.span("lesion.edit", {"fibers": int(ids.size)}):
+        fib = np.asarray(phi.fibers, np.int64)
+        keep = np.nonzero(~np.isin(fib, ids))[0]
+        sub = phi.take(jnp.asarray(keep, jnp.int32))
     w_true = np.asarray(problem.w_true).copy()
     w_true[ids] = 0.0
     stats = dict(problem.stats)

@@ -251,10 +251,11 @@ class _Bucket:
         if self._engine is None or self._engine_sig != sig:
             cfg = self._config(base)
             if self.solo:
-                self._engine = LifeEngine(self.jobs[0].problem, cfg, cache)
+                self._engine = LifeEngine(self.jobs[0].problem, cfg, cache,
+                                          jobs=sig)
             else:
                 self._engine = BatchedLifeEngine(
-                    [j.problem for j in self.jobs], cfg, cache)
+                    [j.problem for j in self.jobs], cfg, cache, jobs=sig)
             self._engine_sig = sig
         # pin the searched dtype the moment it resolves: engine rebuilds
         # (member churn) and checkpoint manifests must see the numerics
@@ -478,7 +479,7 @@ class Scheduler:
             try:
                 with obs.span("scheduler.slice",
                               {"format": bucket.format,
-                               "jobs": len(bucket.jobs)}):
+                               "jobs": tuple(j.job_id for j in bucket.jobs)}):
                     finished = bucket.run_slice(self.config, self.cache,
                                                 self.slice_iters)
             except Exception as exc:

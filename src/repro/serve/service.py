@@ -125,77 +125,81 @@ class LifeService:
             while f"job-{n}" in taken:
                 n += 1
             job_id = f"job-{n}"
-        now = time.monotonic()
-        job = Job(job_id=job_id, problem=problem,
-                  n_iters=self.config.n_iters if n_iters is None else n_iters,
-                  priority=0 if priority is None else priority,
-                  deadline=None if deadline is None else now + deadline,
-                  format=self.config.format if format is None else format,
-                  mesh=None if mesh is None else tuple(mesh),
-                  tune=tune, compute_dtype=compute_dtype, w0=w0,
-                  submitted_at=now, dataset=dataset_key(problem))
-        if job_id in self._resumable:
-            if w0 is not None:
-                raise ValueError(
-                    f"resume of job {job_id!r} rejected: a checkpointed "
-                    f"state exists and is the warm start; w0 would "
-                    f"silently discard it")
-            arrays, meta = self._resumable[job_id]
-            if meta.get("dataset") != job.dataset:
-                raise ValueError(
-                    f"resume of job {job_id!r} rejected: resubmitted data "
-                    f"digest {job.dataset} != checkpointed "
-                    f"{meta.get('dataset')}")
-            ck_format = str(meta.get("format", job.format))
-            if format is not None and format != ck_format:
-                raise ValueError(
-                    f"resume of job {job_id!r} rejected: checkpointed state "
-                    f"ran under format {ck_format!r}, resubmitted with "
-                    f"{format!r}")
-            ck_mesh = meta.get("mesh")
-            ck_mesh = None if ck_mesh is None else tuple(int(x)
-                                                         for x in ck_mesh)
-            if mesh is not None and tuple(mesh) != ck_mesh:
-                raise ValueError(
-                    f"resume of job {job_id!r} rejected: checkpointed state "
-                    f"ran on mesh {ck_mesh}, resubmitted with {tuple(mesh)}")
-            ck_dtype = meta.get("compute_dtype")
-            if (compute_dtype is not None and ck_dtype is not None
-                    and compute_dtype != ck_dtype):
-                raise ValueError(
-                    f"resume of job {job_id!r} rejected: checkpointed state "
-                    f"ran under compute_dtype {ck_dtype!r}, resubmitted "
-                    f"with {compute_dtype!r}")
-            # validation passed — adopt the state (the entry is consumed
-            # only once scheduler.submit accepts the job: its own
-            # validation, e.g. the restored mesh not fitting this host's
-            # devices, must leave the checkpointed state re-adoptable)
-            job.format = ck_format
-            job.mesh = ck_mesh
-            if compute_dtype is None and ck_dtype is not None:
-                job.compute_dtype = str(ck_dtype)
-            if tune is None and meta.get("tune") is not None:
-                job.tune = str(meta["tune"])
-            job.state = SbbnnlsState(w=jnp.asarray(arrays["w"]),
-                                     it=jnp.asarray(arrays["it"]),
-                                     loss=jnp.asarray(arrays["loss"]))
-            job.done = int(meta["done"])
-            # the resume leg restarts submitted_at; the time the job spent
-            # in earlier incarnations is restored so latency is end-to-end
-            job.prior_elapsed = float(meta.get("elapsed", 0.0) or 0.0)
-            # explicit caller arguments win over checkpointed values
-            if n_iters is None:
-                job.n_iters = int(meta.get("n_iters", job.n_iters))
-            if priority is None:
-                job.priority = int(meta.get("priority", 0))
-            if deadline is None and meta.get("deadline_remaining") is not None:
-                job.deadline = now + float(meta["deadline_remaining"])
-            if "losses" in arrays:
-                job.losses = [np.asarray(arrays["losses"])]
-            self._m_resumed.inc()
-        self.scheduler.submit(job)
-        self._resumable.pop(job_id, None)
-        return job_id
+        with obs.span("service.submit", {"job": job_id}):
+            now = time.monotonic()
+            job = Job(job_id=job_id, problem=problem,
+                      n_iters=(self.config.n_iters if n_iters is None
+                               else n_iters),
+                      priority=0 if priority is None else priority,
+                      deadline=None if deadline is None else now + deadline,
+                      format=self.config.format if format is None else format,
+                      mesh=None if mesh is None else tuple(mesh),
+                      tune=tune, compute_dtype=compute_dtype, w0=w0,
+                      submitted_at=now, dataset=dataset_key(problem))
+            if job_id in self._resumable:
+                if w0 is not None:
+                    raise ValueError(
+                        f"resume of job {job_id!r} rejected: a checkpointed "
+                        f"state exists and is the warm start; w0 would "
+                        f"silently discard it")
+                arrays, meta = self._resumable[job_id]
+                if meta.get("dataset") != job.dataset:
+                    raise ValueError(
+                        f"resume of job {job_id!r} rejected: resubmitted data "
+                        f"digest {job.dataset} != checkpointed "
+                        f"{meta.get('dataset')}")
+                ck_format = str(meta.get("format", job.format))
+                if format is not None and format != ck_format:
+                    raise ValueError(
+                        f"resume of job {job_id!r} rejected: checkpointed "
+                        f"state ran under format {ck_format!r}, "
+                        f"resubmitted with {format!r}")
+                ck_mesh = meta.get("mesh")
+                ck_mesh = None if ck_mesh is None else tuple(int(x)
+                                                             for x in ck_mesh)
+                if mesh is not None and tuple(mesh) != ck_mesh:
+                    raise ValueError(
+                        f"resume of job {job_id!r} rejected: checkpointed "
+                        f"state ran on mesh {ck_mesh}, resubmitted with "
+                        f"{tuple(mesh)}")
+                ck_dtype = meta.get("compute_dtype")
+                if (compute_dtype is not None and ck_dtype is not None
+                        and compute_dtype != ck_dtype):
+                    raise ValueError(
+                        f"resume of job {job_id!r} rejected: checkpointed "
+                        f"state ran under compute_dtype {ck_dtype!r}, "
+                        f"resubmitted with {compute_dtype!r}")
+                # validation passed — adopt the state (the entry is consumed
+                # only once scheduler.submit accepts the job: its own
+                # validation, e.g. the restored mesh not fitting this host's
+                # devices, must leave the checkpointed state re-adoptable)
+                job.format = ck_format
+                job.mesh = ck_mesh
+                if compute_dtype is None and ck_dtype is not None:
+                    job.compute_dtype = str(ck_dtype)
+                if tune is None and meta.get("tune") is not None:
+                    job.tune = str(meta["tune"])
+                job.state = SbbnnlsState(w=jnp.asarray(arrays["w"]),
+                                         it=jnp.asarray(arrays["it"]),
+                                         loss=jnp.asarray(arrays["loss"]))
+                job.done = int(meta["done"])
+                # the resume leg restarts submitted_at; the time the job spent
+                # in earlier incarnations is restored so latency is end-to-end
+                job.prior_elapsed = float(meta.get("elapsed", 0.0) or 0.0)
+                # explicit caller arguments win over checkpointed values
+                if n_iters is None:
+                    job.n_iters = int(meta.get("n_iters", job.n_iters))
+                if priority is None:
+                    job.priority = int(meta.get("priority", 0))
+                remaining = meta.get("deadline_remaining")
+                if deadline is None and remaining is not None:
+                    job.deadline = now + float(remaining)
+                if "losses" in arrays:
+                    job.losses = [np.asarray(arrays["losses"])]
+                self._m_resumed.inc()
+            self.scheduler.submit(job)
+            self._resumable.pop(job_id, None)
+            return job_id
 
     # -- driving -----------------------------------------------------------
     def step(self) -> List[Job]:
