@@ -8,15 +8,23 @@ coefficient count Nc_s.  This engine:
 
   1. restructures every subject's Phi per the chosen executor (the same
      per-op sorts :mod:`repro.core.registry` applies for one subject),
-  2. pads each subject's coefficient arrays to the cohort max Nc with inert
-     dummy slots — value 0 so padding contributes nothing through either
-     SpMV, and sort-key index = (dim size - 1) so the padded tail preserves
-     the sortedness the segment-sum executors rely on (the same dummy-slot
-     idiom as ``kernels/ops.py:_padded_operands``),
-  3. stacks the cohort into (S, Nc_max) operands and runs SBBNNLS for all
-     subjects at once: one ``lax.scan`` whose body is the vmapped solver
+  2. pads each subject's coefficient arrays to the ladder size above the
+     cohort max Nc (:func:`ladder_size`) with inert dummy slots — value 0
+     so padding contributes nothing through either SpMV, and sort-key
+     index = (dim size - 1) so the padded tail preserves the sortedness the
+     segment-sum executors rely on (the same dummy-slot idiom as
+     ``kernels/ops.py:_padded_operands``),
+  3. stacks the cohort into (S, Nc_padded) operands and runs SBBNNLS for
+     all subjects at once: one ``lax.scan`` whose body is the vmapped solver
      step, so the per-iteration Barzilai-Borwein step size stays
      *per-subject* while every SpMV becomes one batched device computation.
+
+Steps 1-3 run in numpy and the operands reach the device in one
+``device_put``, so building an engine compiles nothing at a per-subject
+shape (only the signals' stack, once per cohort size).  The solver itself
+is one jitted runner per recipe (:func:`_runner_for`), shared by every
+engine: the dictionary is an argument, not a constant, so a new subject
+whose Nc lands on a ladder size already seen reuses the compiled program.
 
 Batching composes with the plan cache: the "auto" path autotunes once (on
 the first subject, through the persistent cache) and applies the measured
@@ -29,16 +37,17 @@ Mesh placement (DESIGN.md §9): with ``shard_rows * shard_cols > 1`` the
 stacked cohort is laid out over the same (``data``, ``model``) mesh the
 sharded executors use — *subjects* shard over the batch (``data``) axis and
 the stacked Phi coefficient slots over ``model`` — by ``device_put``-ing
-the operands under NamedShardings and letting GSPMD partition the vmapped
-solve.  An axis whose size does not divide its mesh axis stays replicated
-(jax requires even chunks for explicit placement); results are unchanged
-either way, only the partitioning differs.
+the operands under NamedShardings (the dictionary replicated) and letting
+GSPMD partition the vmapped solve.  An axis whose size does not divide its
+mesh axis stays replicated (jax requires even chunks for explicit
+placement); results are unchanged either way, only the partitioning
+differs.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +57,6 @@ from repro import obs
 from repro.core import spmv
 from repro.core.plan_cache import PlanCache
 from repro.core.registry import _DSC_FNS, _WC_FNS, REGISTRY
-from repro.core.restructure import sort_by_host
 from repro.core.sbbnnls import SbbnnlsState, sbbnnls_step
 from repro.core.std import PhiTensor
 from repro.data.dmri import LifeProblem
@@ -65,9 +73,32 @@ _BATCH_RECIPES = {
 _SEGMENT_SORTED = {(spmv.dsc, "voxel"), (spmv.wc, "fiber")}
 
 
+# Ladder sizes sit this many coefficients above m * 2**e.  At a multiple of
+# 1024 the v5e compiler tiles the [Nc, 1] index operands of the dictionary
+# gathers as T(1,128), and one batched iteration (one subject, Ntheta 96)
+# took 73.7 ms at Nc = 2^20 against 69.7 ms at 2^20 + 128 and 68.5 ms at
+# the unpadded 1,014,564 (a TPU v5e).
+_LADDER_OFFSET = 128
+
+
+def ladder_size(nc: int) -> int:
+    """The padded coefficient count for a cohort whose largest Nc is ``nc``.
+
+    The smallest ``m * 2**e + 128 >= nc`` with ``m`` in 8..15: eight sizes
+    an octave, so padding stays under 1/8 of ``nc`` (about 4.5% on average)
+    while subjects of similar size share one size, and with it one compiled
+    runner.  Sizes below 144 are their own ladder value."""
+    y = nc - _LADDER_OFFSET
+    if y < 16:
+        return nc
+    e = y.bit_length() - 4
+    return (-(-y >> e) << e) + _LADDER_OFFSET
+
+
 def _pad_sorted(phi: PhiTensor, nc_max: int, sort_dim: Optional[str],
                 keep_sorted: bool) -> PhiTensor:
-    """Pad a (possibly sorted) PhiTensor to nc_max inert dummy coefficients."""
+    """Pad a (possibly sorted) PhiTensor to nc_max inert dummy coefficients
+    (host arrays)."""
     pad = nc_max - phi.n_coeffs
     if pad == 0:
         return phi
@@ -76,32 +107,66 @@ def _pad_sorted(phi: PhiTensor, nc_max: int, sort_dim: Optional[str],
 
     def pad_idx(arr, dim):
         fill = dim_last[dim] if (keep_sorted and dim == sort_dim) else 0
-        return jnp.concatenate(
-            [arr, jnp.full((pad,), fill, arr.dtype)])
+        return np.concatenate([arr, np.full((pad,), fill, arr.dtype)])
 
     return dataclasses.replace(
         phi,
         atoms=pad_idx(phi.atoms, "atom"),
         voxels=pad_idx(phi.voxels, "voxel"),
         fibers=pad_idx(phi.fibers, "fiber"),
-        values=jnp.concatenate(
-            [phi.values, jnp.zeros((pad,), phi.values.dtype)]))
+        values=np.concatenate(
+            [phi.values, np.zeros((pad,), phi.values.dtype)]))
 
 
 def _stack_phis(phis: Sequence[PhiTensor]) -> PhiTensor:
     return dataclasses.replace(
         phis[0],
-        atoms=jnp.stack([p.atoms for p in phis]),
-        voxels=jnp.stack([p.voxels for p in phis]),
-        fibers=jnp.stack([p.fibers for p in phis]),
-        values=jnp.stack([p.values for p in phis]))
+        atoms=np.stack([p.atoms for p in phis]),
+        voxels=np.stack([p.voxels for p in phis]),
+        fibers=np.stack([p.fibers for p in phis]),
+        values=np.stack([p.values for p in phis]))
+
+
+# jitted runners by (dsc fn, wc fn, solver step): the objects the trace
+# reads.  Functions only; JAX's cache inside each runner keys its compiled
+# programs on the operands' shapes, dtypes and shardings and on n_iters.
+_RUNNERS: Dict[Tuple[Callable, Callable, Callable], Callable] = {}
+
+
+def _runner_for(dsc_fn, wc_fn, step) -> Callable:
+    """The shared jitted runner of one recipe; counts the lookup."""
+    key = (dsc_fn, wc_fn, step)
+    runner = _RUNNERS.get(key)
+    obs.counter("engine.runner.lookups",
+                outcome="miss" if runner is None else "hit").inc()
+    if runner is None:
+        runner = _RUNNERS.setdefault(key, jax.jit(
+            _make_runner(dsc_fn, wc_fn, step), static_argnames=("n_iters",)))
+    return runner
+
+
+def _make_runner(dsc_fn, wc_fn, step):
+    def run_batch(phi_dsc, phi_wc, b, d, states, *, n_iters: int):
+        def one_step(phi_v, phi_w, b_s, state):
+            return step(lambda w: dsc_fn(phi_v, d, w),
+                        lambda y: wc_fn(phi_w, d, y), b_s, state)
+
+        def body(ss, _):
+            new = jax.vmap(one_step)(phi_dsc, phi_wc, b, ss)
+            return new, new.loss
+
+        final, losses = jax.lax.scan(body, states, xs=None, length=n_iters)
+        return final, losses.T            # states, (S, n_iters)
+
+    return run_batch
 
 
 class BatchedLifeEngine:
     """Runs SBBNNLS for a cohort of subjects in one vmapped computation.
 
     All subjects must share the dictionary shape and the (Nv, Nf) problem
-    geometry; coefficient counts may differ (padded to the cohort max).
+    geometry; coefficient counts may differ (padded to the ladder size
+    above the cohort max).
     """
 
     def __init__(self, problems: Sequence[LifeProblem], config,
@@ -138,9 +203,12 @@ class BatchedLifeEngine:
         self.inspector_seconds = 0.0
         self.jobs = tuple(jobs)
         self.mesh = self._make_mesh()
+        self.nc_padded = ladder_size(
+            max(p.phi.n_coeffs for p in self.problems))
         with obs.span("engine.build", {
                 "engine": "batched", "jobs": self.jobs,
-                "nc": sum(p.phi.n_coeffs for p in self.problems)}):
+                "nc": sum(p.phi.n_coeffs for p in self.problems),
+                "nc_padded": self.nc_padded}):
             self._build()
 
     def _make_mesh(self):
@@ -157,21 +225,23 @@ class BatchedLifeEngine:
         return jax.make_mesh((R, C), ("data", "model"),
                              axis_types=(AxisType.Auto,) * 2)
 
-    def _place_on_mesh(self) -> None:
-        """Subjects over the batch (`data`) axis, Phi slots over `model`.
+    def _placement(self):
+        """Shardings of (phi_dsc, phi_wc, b, d): subjects over the batch
+        (`data`) axis, Phi slots over `model`, the dictionary replicated;
+        None (default device) without a mesh.
 
         Axes that don't divide their mesh axis stay replicated (jax needs
         even chunks for device_put); GSPMD keeps results identical."""
+        if self.mesh is None:
+            return None
         from jax.sharding import NamedSharding, PartitionSpec as P
         subj = ("data" if self.n_subjects % self.mesh.shape["data"] == 0
                 else None)
         slot = ("model" if self.nc_padded % self.mesh.shape["model"] == 0
                 else None)
         phi_sh = NamedSharding(self.mesh, P(subj, slot))
-        b_sh = NamedSharding(self.mesh, P(subj, None, None))
-        self.phi_dsc = jax.device_put(self.phi_dsc, phi_sh)
-        self.phi_wc = jax.device_put(self.phi_wc, phi_sh)
-        self.b = jax.device_put(self.b, b_sh)
+        return (phi_sh, phi_sh, NamedSharding(self.mesh, P(subj, None, None)),
+                NamedSharding(self.mesh, P()))
 
     # -- inspector ----------------------------------------------------------
     def _resolve_recipe(self):
@@ -228,13 +298,13 @@ class BatchedLifeEngine:
         t0 = time.perf_counter()
         self._compute_dtype = self._resolve_tuning()
         dsc_dim, wc_dim, self._dsc_fn, self._wc_fn = self._resolve_recipe()
-        nc_max = max(p.phi.n_coeffs for p in self.problems)
-        self.nc_padded = nc_max
 
         def prep(phi: PhiTensor, dim: Optional[str], fn) -> PhiTensor:
-            sorted_phi = sort_by_host(phi, dim)[0] if dim else phi
+            if dim:       # stable sort of the host coefficients along dim
+                order = np.argsort(getattr(phi, dim + "s"), kind="stable")
+                phi = jax.tree_util.tree_map(lambda a: a[order], phi)
             keep_sorted = (fn, dim) in _SEGMENT_SORTED
-            return _pad_sorted(sorted_phi, nc_max, dim, keep_sorted)
+            return _pad_sorted(phi, self.nc_padded, dim, keep_sorted)
 
         phis = [p.phi for p in self.problems]
         if self._alto_order:
@@ -242,27 +312,29 @@ class BatchedLifeEngine:
             # (locality in every mode at once; scatter executors above)
             from repro.formats.alto import AltoPhi
             phis = [AltoPhi.encode(phi).sort()[0].decode() for phi in phis]
+        phis = [jax.tree_util.tree_map(np.asarray, phi) for phi in phis]
 
-        self.phi_dsc = _stack_phis(
+        phi_dsc = _stack_phis(
             [prep(phi, dsc_dim, self._dsc_fn) for phi in phis])
-        self.phi_wc = _stack_phis(
+        phi_wc = _stack_phis(
             [prep(phi, wc_dim, self._wc_fn) for phi in phis])
-        self.b = jnp.stack([p.b for p in self.problems])
-        self._d_op = self.dictionary
+        # the signals keep their device copies: one stack at (S, Nv, Ntheta),
+        # a shape every subject of the geometry shares
+        b = jnp.stack([p.b for p in self.problems])
+        d = np.asarray(self.dictionary)
         if self._compute_dtype == "bf16":
             # bf16 storage of the static operands (stacked Phi values + the
             # shared dictionary); w/Y/b stay fp32 so every product promotes
             # to fp32 before the segment reductions (DESIGN.md §10.3)
             store = jnp.bfloat16
-            self.phi_dsc = dataclasses.replace(
-                self.phi_dsc, values=self.phi_dsc.values.astype(store))
-            self.phi_wc = dataclasses.replace(
-                self.phi_wc, values=self.phi_wc.values.astype(store))
-            self._d_op = jnp.asarray(self.dictionary).astype(store)
-        if self.mesh is not None:
-            self._place_on_mesh()
-        self._runner = jax.jit(self._make_runner(),
-                               static_argnames=("n_iters",))
+            phi_dsc = dataclasses.replace(
+                phi_dsc, values=phi_dsc.values.astype(store))
+            phi_wc = dataclasses.replace(
+                phi_wc, values=phi_wc.values.astype(store))
+            d = d.astype(store)
+        self.phi_dsc, self.phi_wc, self.b, self._d_op = jax.device_put(
+            (phi_dsc, phi_wc, b, d), self._placement())
+        self._runner = _runner_for(self._dsc_fn, self._wc_fn, sbbnnls_step)
         self.inspector_seconds += time.perf_counter() - t0
 
     @property
@@ -270,25 +342,6 @@ class BatchedLifeEngine:
         """Storage dtype the stacked operands were built under (the tune
         plan's winner when ``compute_dtype="auto"`` was searched)."""
         return self._compute_dtype
-
-    def _make_runner(self):
-        d = self._d_op
-        dsc_fn, wc_fn = self._dsc_fn, self._wc_fn
-
-        def run_batch(phi_dsc, phi_wc, b, states, *, n_iters: int):
-            def one_step(phi_v, phi_w, b_s, state):
-                return sbbnnls_step(lambda w: dsc_fn(phi_v, d, w),
-                                    lambda y: wc_fn(phi_w, d, y), b_s, state)
-
-            def body(ss, _):
-                new = jax.vmap(one_step)(phi_dsc, phi_wc, b, ss)
-                return new, new.loss
-
-            final, losses = jax.lax.scan(body, states, xs=None,
-                                         length=n_iters)
-            return final, losses.T            # states, (S, n_iters)
-
-        return run_batch
 
     # -- driver --------------------------------------------------------------
     def init_states(self, w0: Optional[jax.Array] = None) -> SbbnnlsState:
@@ -311,14 +364,14 @@ class BatchedLifeEngine:
         trace)."""
         if not obs.SWITCH.on:
             new, losses = self._runner(self.phi_dsc, self.phi_wc, self.b,
-                                       states, n_iters=k)
+                                       self._d_op, states, n_iters=k)
             return new, np.asarray(losses)
         with obs.span("engine.step", {"executor": self.config.executor,
                                       "batched": self.n_subjects, "k": k,
                                       "jobs": self.jobs}):
             t0 = time.perf_counter()
             new, losses = self._runner(self.phi_dsc, self.phi_wc, self.b,
-                                       states, n_iters=k)
+                                       self._d_op, states, n_iters=k)
             losses = np.asarray(losses)   # host transfer blocks on the scan
             obs.histogram("engine.step.seconds",
                           executor=self.config.executor).observe(
@@ -331,7 +384,8 @@ class BatchedLifeEngine:
         """Solve all subjects; returns (W (S, Nf), losses (S, n_iters))."""
         n_iters = self.config.n_iters if n_iters is None else n_iters
         final, losses = self._runner(self.phi_dsc, self.phi_wc, self.b,
-                                     self.init_states(w0), n_iters=n_iters)
+                                     self._d_op, self.init_states(w0),
+                                     n_iters=n_iters)
         return final.w, np.asarray(losses)
 
     def prune_stats(self, w_batch: jax.Array,

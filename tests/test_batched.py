@@ -1,9 +1,12 @@
 """BatchedLifeEngine: cohort results must match per-subject engines."""
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.batched import BatchedLifeEngine, _pad_sorted
+from repro.core.batched import BatchedLifeEngine, _pad_sorted, ladder_size
 from repro.core.life import LifeConfig, LifeEngine
 from repro.core.registry import REGISTRY
 from repro.core.restructure import sort_by_host
@@ -16,9 +19,16 @@ def cohort():
                         n_atoms=24, grid=(10, 10, 10))
 
 
-@pytest.mark.parametrize("executor", ["naive", "opt", "opt-paper"])
-def test_batched_matches_per_subject(cohort, executor):
-    cfg = LifeConfig(executor=executor, n_iters=12, plan_cache_dir="")
+@pytest.mark.parametrize("executor,extra", [
+    pytest.param("naive", {}, id="naive"),
+    pytest.param("opt", {}, id="opt"),
+    pytest.param("opt-paper", {}, id="opt-paper"),
+    pytest.param("opt", {"compute_dtype": "bf16"}, id="opt-bf16"),
+    pytest.param("opt", {"format": "alto"}, id="alto"),
+])
+def test_batched_matches_per_subject(cohort, executor, extra):
+    cfg = LifeConfig(executor=executor, n_iters=12, plan_cache_dir="",
+                     **extra)
     beng = BatchedLifeEngine(cohort, cfg)
     W, losses = beng.run()
     assert W.shape == (3, cohort[0].phi.n_fibers)
@@ -122,3 +132,94 @@ def test_batched_mesh_placement_matches_unplaced(cohort, R, C):
     np.testing.assert_allclose(np.asarray(W1), np.asarray(W0),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(L1, L0, rtol=1e-4)
+
+
+# ----------------------------------------------------------------------------
+# the Nc ladder and the shared runner (DESIGN.md §6.2)
+# ----------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nc", [0, 1, 7, 8, 15, 16, 17, 31, 127, 128, 143,
+                                144, 145, 271, 272, 273, 1000, 4095, 4096,
+                                4097, 2 ** 20 - 1, 2 ** 20, 2 ** 20 + 1,
+                                2 ** 20 + 129, 1_000_007, 1_016_359,
+                                3 * 2 ** 30 + 5])
+def test_ladder_size(nc):
+    size = ladder_size(nc)
+    assert size >= nc
+    assert size - nc <= nc / 8 and (nc == 0 or size - nc < nc / 8)
+    assert ladder_size(size) == size                 # a ladder value is fixed
+    assert ladder_size(max(0, nc - 1)) <= size <= ladder_size(nc + 1)
+    base = size - 128                                # m * 2**e, m in 8..15
+    if base >= 16:
+        e = base.bit_length() - 4
+        assert base % 2 ** e == 0 and 8 <= base >> e <= 15
+    else:
+        assert size == nc
+    if nc > 8192 + 128:               # never a multiple of 1024 (v5e layout)
+        assert size % 1024 == 128
+
+
+def test_ladder_size_monotone_with_eight_sizes_an_octave():
+    sizes = [ladder_size(n) for n in range(1, 5000)]
+    assert all(a <= b for a, b in zip(sizes, sizes[1:]))
+    for lo in (16, 256, 2048):
+        assert len({s - 128 for s in sizes if lo <= s - 128 < 2 * lo}) == 8
+
+
+def _subset(p, n, scale):
+    """A different subject with the first ``n`` coefficients of ``p``."""
+    phi = jax.tree_util.tree_map(lambda a: a[:n], p.phi)
+    return dataclasses.replace(
+        p, phi=dataclasses.replace(phi, values=phi.values * scale))
+
+
+def test_runner_is_shared_across_subjects_in_one_bucket(cohort, monkeypatch):
+    """A second engine on another subject whose Nc lands on the same ladder
+    size builds and steps without compiling; a subject on another ladder
+    size compiles; an engine built after the solver step is replaced gets
+    a runner of its own."""
+    import jax.monitoring as mon
+
+    import repro.core.batched as batched
+
+    p = cohort[0]
+    nc = p.phi.n_coeffs
+    same = _subset(p, nc - 3, 0.5)
+    other = _subset(p, nc // 2, 2.0)
+    assert ladder_size(same.phi.n_coeffs) == ladder_size(nc)
+    assert ladder_size(other.phi.n_coeffs) != ladder_size(nc)
+    cfg = LifeConfig(executor="opt", n_iters=4, plan_cache_dir="")
+    compiles = []
+
+    def count(event, *_, **__):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def build_and_step(problem):
+        del compiles[:]
+        eng = BatchedLifeEngine([problem], cfg)
+        states, _ = eng.step(eng.init_states(), 3)
+        jax.block_until_ready(states)
+        return eng, states, len(compiles)
+
+    mon.register_event_duration_secs_listener(count)
+    try:
+        first, _, _ = build_and_step(p)
+        second, states, n_same = build_and_step(same)
+        _, _, n_other = build_and_step(other)
+        monkeypatch.setattr(batched, "sbbnnls_step",
+                            lambda mv, rmv, b, state: state)
+        patched, frozen, n_patched = build_and_step(p)
+    finally:
+        mon.unregister_event_duration_listener(count)
+    assert second._runner is first._runner
+    assert n_same == 0
+    assert n_other >= 1
+    assert patched._runner is not first._runner and n_patched >= 1
+    np.testing.assert_array_equal(np.asarray(frozen.w), 1.0)
+    # the shared runner solved the second subject, not the first
+    w_ref, _ = LifeEngine(same, cfg).run(n_iters=3)
+    np.testing.assert_allclose(np.asarray(states.w[0]), np.asarray(w_ref),
+                               rtol=1e-5, atol=1e-6)
+    monkeypatch.undo()
+    assert BatchedLifeEngine([same], cfg)._runner is first._runner

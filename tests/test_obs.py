@@ -306,6 +306,7 @@ def test_spans_reach_the_profiler_trace(tiny_cohort, tmp_path):
     import jax
     from jax.profiler import ProfileData
 
+    from repro.core.batched import ladder_size
     from repro.core.life import LifeConfig
     from repro.serve import LifeService
 
@@ -329,10 +330,43 @@ def test_spans_reach_the_profiler_trace(tiny_cohort, tmp_path):
     assert stats["service.submit"]["job"] == "a1"
     assert stats["engine.build"]["jobs"] == "a1"
     assert stats["engine.build"]["nc"] == tiny_cohort[0].phi.n_coeffs
+    assert stats["engine.build"]["nc_padded"] == ladder_size(
+        tiny_cohort[0].phi.n_coeffs)
     assert stats["engine.step"]["jobs"] == "a1"
     assert stats["engine.step"]["k"] == 4
     assert stats["scheduler.slice"]["jobs"] == "a1"
     assert "scheduler.tick" in stats
+
+
+def test_batched_runner_lookups_and_padded_build(tiny_cohort, monkeypatch):
+    """Two engines on subjects of one ladder size: the first misses the
+    runner memo, the second hits it; each build span carries nc_padded."""
+    import dataclasses
+
+    import jax
+
+    import repro.core.batched as batched
+    from repro.core.life import LifeConfig
+
+    monkeypatch.setattr(batched, "_RUNNERS", {})
+    p = tiny_cohort[0]
+    n = p.phi.n_coeffs - 2
+    other = dataclasses.replace(
+        p, phi=jax.tree_util.tree_map(lambda a: a[:n], p.phi))
+    assert batched.ladder_size(n) == batched.ladder_size(p.phi.n_coeffs)
+    cfg = LifeConfig(executor="opt", n_iters=4, plan_cache_dir="")
+    obs.enable()
+    batched.BatchedLifeEngine([p], cfg)
+    assert obs.value("engine.runner.lookups", outcome="miss") == 1.0
+    assert obs.value("engine.runner.lookups", outcome="hit") == 0.0
+    batched.BatchedLifeEngine([other], cfg)
+    assert obs.value("engine.runner.lookups", outcome="miss") == 1.0
+    assert obs.value("engine.runner.lookups", outcome="hit") == 1.0
+    builds = [s["attrs"] for s in obs.TRACER.export()
+              if s["name"] == "engine.build"]
+    assert [b["nc"] for b in builds] == [p.phi.n_coeffs, n]
+    assert [b["nc_padded"] for b in builds] == [
+        batched.ladder_size(p.phi.n_coeffs)] * 2
 
 
 def test_solver_ops_carry_their_scope(tiny_problem):
