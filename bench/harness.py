@@ -14,18 +14,24 @@ as a user gets it, and for a virtual lesion the program's own edit
 Set-up makes the subjects from the seed (the benchmark's own programs
 compile with the persistent cache on), starts the front end, turns the
 persistent cache off and runs one job of the mix's kind on a subject or
-bundle the window never sees.  So every compile the program does, in the
-warm-up and in the window, is equally cold in every run and for every
-job: with the warm-up's programs read from the cache instead, the
-compiler itself would first run inside the window, and a seed's second
-run would read its first solve about 5 s slower than its first run did.
-Then one client sends jobs until the window's seconds have passed.
-After the window the program is shut down, the device's peak memory is
-read, the persistent cache is turned on again for the benchmark's own
-programs, and the reference solves the answers the window compares.
+bundle the window never sees; an open mix instead runs its own schedule
+for ``warmup_s`` on ``warmup_subjects`` the window never draws.  So every
+compile the program does, in the warm-up and in the window, is equally
+cold in every run and for every job: with the warm-up's programs read
+from the cache instead, the compiler itself would first run inside the
+window, and a seed's second run would read its first solve about 5 s
+slower than its first run did.  Then one client sends jobs until the
+window's seconds have passed: in a closed loop each when the last answer
+is in, in an open loop each at its due time (:func:`open_window`).  A
+traced run turns the program's own tracing on for the window.  After the
+window the program is shut down, the device's peak memory is read, the
+persistent cache is turned on again for the benchmark's own programs, and
+the reference solves the answers the window compares.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import gc
 import glob
@@ -48,6 +54,8 @@ ROOT = Path(__file__).resolve().parents[1]
 CACHE = ROOT / ".bench_cache"
 #: longest wait for one answer
 RESULT_TIMEOUT_S = 300.0
+#: how often the open loop's sender looks for answers
+POLL_S = 0.002
 #: isolated DSC / WC calls per traced run
 ISOLATED_CALLS = 10
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -71,6 +79,16 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} (have {sorted(cells)})")
     return make_cell(cells[workload], spec, root)
+
+
+def cell_from_files(workload: str, root: Path = ROOT) -> Cell:
+    """The one-chip cell ``<config>.<traffic>`` from its files alone, where
+    ``BENCHMARK.json`` does not list it yet: what a sweep for its rate,
+    and the tests, run before the cell is added."""
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    config, traffic_name = workload.split(".", 1)
+    return make_cell({"name": workload, "config": config,
+                      "traffic": traffic_name, "chips": 1}, spec, root)
 
 
 def make_cell(w: dict, spec: dict, root: Path = ROOT) -> Cell:
@@ -151,7 +169,8 @@ class CompileCounter:
 
 @dataclasses.dataclass
 class Job:
-    """One job of the window; times in seconds from the window start."""
+    """One job of the window; times in seconds from the window start.  An
+    open loop's job has its due time and the job id it was sent under."""
 
     req: traffic.Request
     sent: float
@@ -159,6 +178,16 @@ class Job:
     error: Optional[str] = None
     w: object = None
     losses: Optional[np.ndarray] = None
+    due: Optional[float] = None
+    job_id: Optional[str] = None
+
+    @property
+    def latency(self) -> Optional[float]:
+        """Answer time less the due time (the send time in a closed loop),
+        so a late sender does not hide queueing; None until answered."""
+        if self.finished is None or self.error is not None:
+            return None
+        return self.finished - (self.sent if self.due is None else self.due)
 
 
 @dataclasses.dataclass
@@ -242,9 +271,10 @@ class Workload:
         self.d = gen.dictionary(cfg["n_atoms"], cfg["n_theta"])
         lesion = "lesion" in mix
         # a solve mix gets one subject more than the window cycles
-        # through, the warm-up's, so no window job finds its programs
-        self.subjects = gen.subjects(
-            cfg, int(mix["subjects"]) + (0 if lesion else 1), seed)
+        # through, the warm-up's, so no window job finds its programs; an
+        # open mix gets its warm-up subjects after the window's
+        extra = (0 if lesion else int(mix.get("warmup_subjects", 1)))
+        self.subjects = gen.subjects(cfg, int(mix["subjects"]) + extra, seed)
         self.problems = [to_problem(s, self.d) for s in self.subjects]
         self.bundles = []
         if lesion:
@@ -256,10 +286,11 @@ class Workload:
         self.w_full = None            # the set-up solve (lesion mixes)
         self.w_full_losses = None
 
-    def submit(self, fe, req: traffic.Request):
+    def submit(self, fe, req: traffic.Request,
+               job_id: Optional[str] = None):
         if req.bundle is None:
             return fe.submit_async(self.problems[req.subject],
-                                   n_iters=req.n_iters)
+                                   n_iters=req.n_iters, job_id=job_id)
         # a virtual lesion as a user makes it: the program's edit of the
         # full subject, then a warm-started re-solve
         from repro.science.lesion import lesion_problem, warm_start_weights
@@ -273,6 +304,17 @@ class Workload:
         last subject, or for a lesion mix the last bundle, after the full
         solve that the queries warm-start from."""
         mix = self.cell.mix
+        if mix["loop"] == "open":
+            seconds = float(mix["warmup_s"])
+            jobs = open_loop(
+                lambda req, job_id: self.submit(fe, req, job_id),
+                traffic.open_requests(mix, self.seed, seconds, warm_up=True),
+                seconds, RESULT_TIMEOUT_S, prefix="w")
+            bad = [j for j in jobs if j.latency is None]
+            if bad:
+                raise RuntimeError(f"warm-up: {len(bad)} of {len(jobs)} "
+                                   f"jobs not answered ({bad[0].error})")
+            return
         if "lesion" in mix:
             n = int(mix["lesion"]["warm_start_iters"])
             w, losses = fe.submit_async(self.problems[0], n_iters=n).result(
@@ -315,6 +357,98 @@ def closed_window(fe, wl: Workload) -> List[Job]:
         if job.finished >= wl.seconds:
             break
     return jobs
+
+
+def open_window(fe, wl: Workload) -> List[Job]:
+    """Requests sent at their due times for the window's seconds, never
+    waiting for answers; then in-flight jobs drain for at most the mix's
+    ``drain_s``."""
+    mix = wl.cell.mix
+    return open_loop(lambda req, job_id: wl.submit(fe, req, job_id),
+                     traffic.open_requests(mix, wl.seed, wl.seconds),
+                     wl.seconds, float(mix["drain_s"]))
+
+
+def open_loop(submit, schedule: List[traffic.Request], seconds: float,
+              drain_s: float, prefix: str = "") -> List[Job]:
+    """Send each request of ``schedule`` at its due time, as job
+    ``<prefix><index>``, through ``submit(req, job_id) -> handle``, until
+    ``seconds`` have passed; collect answers as they resolve, and after
+    ``seconds`` for at most ``drain_s`` more.  One thread sends and
+    collects, looking for answers every :data:`POLL_S`.  A job's ``sent``
+    less its ``due`` is how late the sender ran."""
+    jobs: List[Job] = []
+    live = []
+    due = collections.deque(schedule)
+    t0 = time.perf_counter()
+    while True:
+        now = time.perf_counter() - t0
+        while due and due[0].at <= now < seconds:
+            req = due.popleft()
+            job = Job(req=req, sent=now, due=req.at,
+                      job_id=f"{prefix}{len(jobs)}")
+            jobs.append(job)
+            with _annotate("bench.submit"):
+                try:
+                    live.append((job, submit(req, job.job_id)))
+                except Exception as exc:   # a refused submission is an answer
+                    job.finished = time.perf_counter() - t0
+                    job.error = repr(exc)
+            now = time.perf_counter() - t0
+        waiting = []
+        for job, handle in live:
+            if handle.done():
+                _collect(job, handle, t0)
+            else:
+                waiting.append((job, handle))
+        live = waiting
+        if now >= seconds and (not live or now >= seconds + drain_s):
+            return jobs
+        wake = due[0].at - now if due and now < seconds else POLL_S
+        time.sleep(min(POLL_S, max(wake, 0.0)))
+
+
+def open_summary(jobs: List[Job], seconds: float) -> dict:
+    """What an open window did: jobs sent and answered, how late the sender
+    ran, and latency (median and 95th percentile, over the answered) by
+    thirds of the send window, by due time."""
+    late = [j.sent - j.due for j in jobs if j.due is not None]
+    thirds = []
+    for k in range(3):
+        part = [j for j in jobs
+                if k * seconds / 3 <= (j.due or 0.0) < (k + 1) * seconds / 3]
+        lat = [j.latency for j in part if j.latency is not None]
+        thirds.append({
+            "jobs": len(part), "answered": len(lat),
+            "p50_s": float(np.percentile(lat, 50)) if lat else None,
+            "p95_s": float(np.percentile(lat, 95)) if lat else None})
+    return {"sent": len(jobs),
+            "answered": sum(j.latency is not None for j in jobs),
+            "late_max_s": max(late, default=0.0),
+            "late_mean_s": float(np.mean(late)) if late else 0.0,
+            "thirds": thirds}
+
+
+#: a window is steady where its first third's median latency is at most
+#: this many unloaded jobs: an M/D/1 queue at 90% load waits 4.5 service
+#: times on average, so a queue the rate can hold reads under 5, and a
+#: window that opens on a backlog (a storm of compiles) reads far above
+STEADY_FACTOR = 5.0
+
+
+def sustained(summary: dict, unloaded_s: float) -> bool:
+    """Every job answered within the drain; the window opens steady (the
+    first third's median latency at most :data:`STEADY_FACTOR` times
+    ``unloaded_s``, an unloaded job's); and latency does not grow (the last
+    third's median within 1.5 times the first third's)."""
+    first, last = summary["thirds"][0], summary["thirds"][2]
+    return (summary["answered"] == summary["sent"] and
+            first["p50_s"] is not None and last["p50_s"] is not None and
+            first["p50_s"] <= STEADY_FACTOR * unloaded_s and
+            last["p50_s"] <= 1.5 * first["p50_s"])
+
+
+WINDOWS = {"closed": closed_window, "open": open_window}
 
 
 # -- isolated SpMV calls (traced runs) -------------------------------------------
@@ -399,7 +533,10 @@ def compare(wl: Workload, jobs: List[Job]) -> Dict[str, float]:
                                   reference.dsc(phi, wl.d, w_full_ref))
         worst_loss = check.loss_gap(wl.w_full_losses, l_full_ref)
         w_full_ref = np.asarray(w_full_ref)
-    for job in checked(wl, jobs):
+    picked = checked(wl, jobs)
+    if not picked:                      # no answer shows nothing correct
+        worst_fit = float("inf")
+    for job in picked:
         key = (job.req.subject, job.req.bundle, job.req.n_iters)
         if key not in ref_cache:
             s, w0 = wl.subjects[job.req.subject], ones
@@ -417,9 +554,14 @@ def compare(wl: Workload, jobs: List[Job]) -> Dict[str, float]:
         worst_fit = max(worst_fit, check.fit_gap(
             reference.dsc(phi, wl.d, job.w), ref_fit))
         worst_loss = max(worst_loss, check.loss_gap(job.losses, ref_losses))
-    missing = sum(j.finished is None or j.error is not None for j in jobs)
     return {"loss_gap": worst_loss, "fit_gap": worst_fit,
-            "missing": float(missing)}
+            "missing": float(missing(jobs))}
+
+
+def missing(jobs: List[Job]) -> int:
+    """Jobs that failed, were refused, or were left unanswered when the
+    window (an open loop's with its drain) closed."""
+    return sum(j.error is not None or j.finished is None for j in jobs)
 
 
 def peak_bytes(devices) -> Optional[int]:
@@ -457,8 +599,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
         if traced:
             jax.profiler.start_trace(trace_dir, profiler_options=_trace_options())
         counter.on = True
-        with _annotate("bench.window"):
-            jobs = closed_window(fe, wl)
+        with program_tracing(traced), _annotate("bench.window"):
+            jobs = WINDOWS[cell.mix["loop"]](fe, wl)
         counter.on = False
         if isolated is not None:
             isolated.run()
@@ -510,8 +652,9 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
                                 default=0.0),
             "memory_peak_bytes": peak, "seed": seed,
             "n_coeffs": [s.n_coeffs for s in wl.subjects][:8],
-            "sent_and_latency_s": [[j.sent, None if j.finished is None
-                                    else j.finished - j.sent] for j in jobs]}
+            "sent_and_latency_s": [[j.sent, j.latency] for j in jobs]}
+    if cell.mix["loop"] == "open":
+        info["open"] = open_summary(jobs, seconds)
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     out = {"correct": correct, "attempted": len(jobs),
@@ -525,6 +668,22 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
     out["checks"] = {k: {"value": v, "limit": cell.limits.get(k)}
                      for k, v in readings.items()}
     return {"result": out, "info": info, "check_lines": lines}
+
+
+@contextlib.contextmanager
+def program_tracing(on: bool):
+    """The program's own tracing (``repro.obs``) reset and on inside the
+    block where ``on`` (a traced window), untouched otherwise."""
+    if not on:
+        yield
+        return
+    from repro import obs
+    obs.reset()
+    obs.enable()
+    try:
+        yield
+    finally:
+        obs.disable()
 
 
 def _trace_options():

@@ -39,12 +39,17 @@ In the recorded chip trace of the front end
 ``PjitFunction(run_batch)`` > ``trace_to_jaxpr_dynamic``,
 ``lower_sharding_computation``, ``backend_compile_and_load``.
 
-The metric readers ``idle_compile_s``, ``idle_build_s``, ``idle_intake_s``,
-``dsc_device_s`` and ``wc_device_s`` call :func:`install` when they are
-loaded, which the harness does at the start of a traced run only: it turns
-the program's tracing (``repro.obs``) on for the window and reads the
-trace file the harness loads.  A program without these spans (or scopes)
-gives no reading, not zero.
+It also keeps each ``engine.step`` in the window with the job ids of its
+``jobs`` stat (:func:`steps`), for the scheduler's readers ``queue_s`` and
+``step_jobs``.
+
+The harness turns the program's tracing (``repro.obs``) on for the window
+of a traced run.  The metric readers ``idle_compile_s``, ``idle_build_s``,
+``idle_intake_s``, ``dsc_device_s``, ``wc_device_s``, ``queue_s`` and
+``step_jobs`` call :func:`install` when they are loaded, which the harness
+does at the start of a traced run only: it analyses the trace file the
+harness loads.  A program without these spans (or scopes) gives no
+reading, not zero.
 """
 from __future__ import annotations
 
@@ -52,6 +57,7 @@ import dataclasses
 import gzip
 import json
 import re
+import statistics
 import sys
 import time
 from collections import defaultdict
@@ -80,6 +86,8 @@ TF_OP, CATEGORY = "tf_op", "hlo_category"
 CONTAINERS = ("(while)", "(conditional)")
 _SCOPE = re.compile(r"(?:^|[/(])sbbnnls\.(dsc|wc|bb)(?=[/):]|$)")
 WINDOW = "bench.window"
+#: host spans whose stats :func:`read` keeps, and the stat kept
+STEP, JOBS = "engine.step", "jobs"
 
 
 # -- the wire format ---------------------------------------------------------
@@ -119,11 +127,23 @@ def _text(value) -> str:
     return bytes(value).decode("utf-8", "replace")
 
 
-def _plane(buf, by_stat: bool):
+def _stat_value(stat: dict, stat_names: dict):
+    """An XStat's value: its string, a reference to an interned string,
+    or its number."""
+    if 5 in stat:
+        return _text(stat[5])
+    if 7 in stat:
+        return stat_names.get(stat[7], "")
+    return next((stat[k] for k in (4, 3) if k in stat), None)
+
+
+def _plane(buf, by_stat: bool, with_stats=()):
     """Name, lines and event names of one XPlane.  Lines are ``(line
-    name, [(metadata id, start s, end s)])``; an event's name is its
+    name, [(metadata id, start s, end s, stats)])``; an event's name is its
     metadata's, or ``by_stat``, its metadata's ``tf_op`` stat, else its
-    ``hlo_category`` in parentheses (``(while)``, ``(custom fusion)``)."""
+    ``hlo_category`` in parentheses (``(while)``, ``(custom fusion)``).
+    ``stats`` is the dict of an event's own stats where its name is in
+    ``with_stats``, else None."""
     name, lines, events, stat_names = "", [], {}, {}
     for f, v in _fields(buf):
         if f == 2:
@@ -147,9 +167,7 @@ def _plane(buf, by_stat: bool):
         for f, v in meta:
             stat = dict(_fields(v)) if f == 5 else {}
             if stat_names.get(stat.get(1)) in (TF_OP, CATEGORY):
-                stats[stat_names[stat[1]]] = (
-                    _text(stat[5]) if 5 in stat
-                    else stat_names.get(stat.get(7), ""))
+                stats[stat_names[stat[1]]] = _stat_value(stat, stat_names)
         names[key] = stats.get(TF_OP) or f"({stats.get(CATEGORY, '')})"
     out = []
     for raw in lines:
@@ -160,10 +178,19 @@ def _plane(buf, by_stat: bool):
             elif f == 3:
                 stamp_ns = v
             elif f == 4:
-                ev = dict(_fields(v))
+                fields = list(_fields(v))
+                ev = dict(fields)
                 start = stamp_ns * 1e-9 + ev.get(2, 0) * 1e-12
+                stats = None
+                if names.get(ev.get(1, 0)) in with_stats:
+                    stats = {}
+                    for g, raw_stat in fields:
+                        if g == 4:
+                            stat = dict(_fields(raw_stat))
+                            stats[stat_names.get(stat.get(1), "")] = \
+                                _stat_value(stat, stat_names)
                 rows.append((ev.get(1, 0), start,
-                             start + ev.get(3, 0) * 1e-12))
+                             start + ev.get(3, 0) * 1e-12, stats))
         out.append((line_name, rows))
     return name, out, names
 
@@ -174,6 +201,9 @@ class Xspace:
 
     ops: Dict[str, trace.Events]    # TPU plane -> XLA ops, by tf_op
     threads: List[trace.Events]     # one per host line, events by name
+    #: (start, end, job ids) of each engine.step on the host
+    steps: List[Tuple[float, float, Tuple[str, ...]]] = \
+        dataclasses.field(default_factory=list)
 
 
 def read(path) -> Xspace:
@@ -182,7 +212,7 @@ def read(path) -> Xspace:
     path = Path(path)
     raw = (gzip.open(path).read() if path.suffix == ".gz"
            else path.read_bytes())
-    ops, threads = {}, []
+    ops, threads, steps = {}, [], []
     for f, plane in _fields(memoryview(raw)):
         if f != 1:
             continue
@@ -192,13 +222,16 @@ def read(path) -> Xspace:
             for line_name, rows in lines:
                 if line_name == trace.OPS_LINE:
                     ops[name] = trace.Events.of(
-                        [(tf_op.get(m, ""), s, e) for m, s, e in rows])
+                        [(tf_op.get(m, ""), s, e) for m, s, e, _ in rows])
         elif name.startswith(trace.HOST_PREFIX):
-            _, lines, names = _plane(plane, False)
+            _, lines, names = _plane(plane, False, (STEP,))
             threads.extend(trace.Events.of(
-                [(names.get(m, ""), s, e) for m, s, e in rows if e > s])
+                [(names.get(m, ""), s, e) for m, s, e, _ in rows if e > s])
                 for _, rows in lines if rows)
-    return Xspace(ops=ops, threads=threads)
+            steps += [(s, e, tuple(str(st.get(JOBS) or "").split()))
+                      for _, rows in lines for _, s, e, st in rows
+                      if st is not None]
+    return Xspace(ops=ops, threads=threads, steps=sorted(steps))
 
 
 # -- interval arithmetic on sorted disjoint lists --------------------------------
@@ -356,7 +389,15 @@ def reduce(xs: Xspace) -> Optional[dict]:
             "spans": any(n in PROGRAM_SPANS for ev in threads
                          for n in ev.names),
             "scopes": scope_seconds(xs, lo, hi),
-            "other_by_span": innermost(left, threads)}
+            "other_by_span": innermost(left, threads),
+            "steps": steps(xs, lo, hi)}
+
+
+def steps(xs: Xspace, lo: float, hi: float
+          ) -> List[Tuple[float, Tuple[str, ...]]]:
+    """``(start, job ids)`` of each ``engine.step`` that starts in
+    ``[lo, hi]``, start in seconds from ``lo``, in order."""
+    return [(s - lo, jobs) for s, _, jobs in xs.steps if lo <= s <= hi]
 
 
 # -- the harness's side (traced runs) ---------------------------------------------
@@ -367,35 +408,28 @@ _INSTALLED = False
 
 
 def install() -> None:
-    """Turn the program's tracing on for the window, and analyse the trace
-    file when the harness loads it (the analysis's totals go to standard
-    error as ``bench.spans {...}``).  Idempotent."""
+    """Analyse the trace file when the harness loads it (the analysis's
+    totals go to standard error as ``bench.spans {...}``, the steps
+    aside).  Idempotent."""
     global _INSTALLED
     if _INSTALLED:
         return
     _INSTALLED = True
-    from bench import harness
-    window_of, load = harness.closed_window, trace.load
-
-    def closed_window(fe, wl):
-        from repro import obs
-        obs.reset()
-        obs.enable()
-        try:
-            return window_of(fe, wl)
-        finally:
-            obs.disable()
+    load = trace.load
 
     def load_and_analyse(path):
         global LAST
         t0 = time.perf_counter()
         LAST = analyse(path)
+        if LAST is not None:
+            shown = {k: v for k, v in LAST.items() if k != "steps"}
+            shown["n_steps"] = len(LAST["steps"])
         print("bench.spans " + json.dumps(
-            {"analyse_s": time.perf_counter() - t0, "window": LAST}),
+            {"analyse_s": time.perf_counter() - t0,
+             "window": shown if LAST is not None else None}),
             file=sys.stderr, flush=True)
         return load(path)
 
-    harness.closed_window = closed_window
     trace.load = load_and_analyse
 
 
@@ -416,3 +450,25 @@ def scope_per_answer(run, scope: str) -> Optional[float]:
                                            for k in SCOPES):
         return None
     return LAST["scopes"][scope] / done
+
+
+def step_jobs() -> Optional[float]:
+    """Mean number of jobs an ``engine.step`` of the window advanced."""
+    if LAST is None or not LAST["steps"]:
+        return None
+    return sum(len(jobs) for _, jobs in LAST["steps"]) / len(LAST["steps"])
+
+
+def queue_seconds(run) -> Optional[float]:
+    """Median over the window's open-loop jobs of the time from the job's
+    due time to the start of the first ``engine.step`` that names it; jobs
+    never stepped in the window are left out."""
+    if LAST is None or not LAST["steps"]:
+        return None
+    first: Dict[str, float] = {}
+    for start, jobs in LAST["steps"]:
+        for job in jobs:
+            first.setdefault(job, start)
+    waits = [first[j.job_id] - j.due for j in run.jobs
+             if j.due is not None and j.job_id in first]
+    return statistics.median(waits) if waits else None

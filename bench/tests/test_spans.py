@@ -170,16 +170,75 @@ def test_recorded_spans_carry_their_jobs():
 
 
 def test_install_turns_obs_on_for_the_window_only(monkeypatch):
+    """The harness turns the program's tracing on around a traced window
+    of either loop (and leaves it alone otherwise); install() only
+    analyses the trace the harness loads."""
     from repro import obs
     seen = []
     monkeypatch.setattr(spans, "_INSTALLED", False)
     monkeypatch.setattr(spans, "LAST", None)
-    monkeypatch.setattr(harness, "closed_window",
-                        lambda fe, wl: seen.append(obs.enabled()) or ["job"])
     monkeypatch.setattr(trace, "load", lambda path: path)
+    windows = dict(harness.WINDOWS)
     spans.install()
     spans.install()                        # idempotent: wraps once
-    assert harness.closed_window(None, None) == ["job"]
-    assert seen == [True] and not obs.enabled()
+    assert harness.WINDOWS == windows      # no window is wrapped
+    with harness.program_tracing(True):
+        seen.append(obs.enabled())
+    with harness.program_tracing(False):
+        seen.append(obs.enabled())
+    assert seen == [True, False] and not obs.enabled()
     assert trace.load(SMALL) == SMALL      # the harness's load still runs
     assert spans.LAST is not None and not spans.LAST["spans"]
+
+
+@pytest.mark.skipif(not RECORDED.exists(), reason="recorded trace absent")
+def test_wire_reader_keeps_each_steps_jobs():
+    """The steps' job ids as the wire reader reads them are those
+    ``jax.profiler.ProfileData`` gives, at the same times."""
+    import gzip
+
+    from jax.profiler import ProfileData
+    data = ProfileData.from_serialized_xspace(gzip.open(RECORDED).read())
+    theirs = sorted(
+        (e.start_ns * 1e-9, tuple(str(dict(e.stats)["jobs"]).split()))
+        for plane in data.planes if plane.name.startswith(trace.HOST_PREFIX)
+        for line in plane.lines for e in line.events
+        if e.name == spans.STEP)
+    mine = spans.read(RECORDED).steps
+    assert len(mine) == len(theirs) > 0
+    for (s, _, jobs), (t, want) in zip(mine, theirs):
+        assert s == pytest.approx(t, abs=1e-6) and jobs == want
+    lo, _ = spans.window(spans.read(RECORDED))
+    out = spans.reduce(spans.read(RECORDED))
+    assert [jobs for _, jobs in out["steps"]] == [j for _, j in theirs]
+    assert out["steps"][0][0] == pytest.approx(theirs[0][0] - lo, abs=1e-6)
+
+
+def test_queue_and_step_jobs_from_steps(monkeypatch):
+    """queue_s: median over jobs of (first step naming the job - due);
+    step_jobs: mean jobs a step; jobs never stepped and closed-loop jobs
+    are left out, and nothing is read without steps."""
+    from bench import traffic
+    xs = spans.Xspace(
+        ops={DEVICE: trace.Events.of([("fusion", 10.0, 11.0)])},
+        threads=[trace.Events.of([("bench.window", 10.0, 20.0)])],
+        steps=[(9.0, 9.5, ("0",)), (10.5, 11.0, ("0",)),
+               (11.0, 12.0, ("0", "1")), (12.5, 13.0, ("1", "2", "3")),
+               (21.0, 22.0, ("4",))])
+    out = spans.reduce(xs)
+    assert [s for s, _ in out["steps"]] == pytest.approx([0.5, 1.0, 2.5])
+    monkeypatch.setattr(spans, "LAST", out)
+
+    def job(jid, due):
+        req = traffic.Request(subject=0, n_iters=1, at=due)
+        return harness.Job(req=req, sent=due or 0.0, due=due, job_id=jid)
+
+    class Run:
+        jobs = [job("0", 0.25), job("1", 0.5), job("2", 1.5), job("4", 2.0),
+                job(None, None)]
+
+    # waits 0.25, 0.5, 1.0 (job 4 never stepped in the window)
+    assert spans.queue_seconds(Run()) == pytest.approx(0.5)
+    assert spans.step_jobs() == pytest.approx((1 + 2 + 3) / 3)
+    monkeypatch.setattr(spans, "LAST", dict(out, steps=[]))
+    assert spans.queue_seconds(Run()) is None and spans.step_jobs() is None

@@ -1,31 +1,43 @@
 """The one traffic generator: reads a mix's parameters, makes its requests.
 
 A mix is a JSON file under ``bench/traffic/`` (see ``bench/README.md``).
-Every seed of a mix gets the same work: one client cycles through the
-mix's subjects, or through the bundles of a lesion mix.  So runs with
-different seeds differ in which subjects and bundles they see, not in how
-much they ask.
+Every seed of a mix gets the same work.  In a closed loop one client
+cycles through the mix's subjects, or through the bundles of a lesion mix.
+In an open loop requests arrive on a schedule that never waits for
+answers: exponential gaps at ``rate_per_s``, each seed the same set of
+gaps in its own order, and subjects drawn by popularity (Zipf).  So runs
+with different seeds differ in which subjects they see and when, not in
+how much they ask.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 from pathlib import Path
-from typing import Iterator, Optional
+from typing import Iterator, List, Optional
 
 import numpy as np
 
-LOOPS = ("closed",)
+LOOPS = ("closed", "open")
+#: an open mix's parameters that must be positive, and those that may be 0
+OPEN_POSITIVE = ("rate_per_s", "warmup_s", "warmup_subjects", "drain_s")
+OPEN_NONNEGATIVE = ("tenants_zipf",)
+#: numbered streams of the seed: the window's arrival order and subjects,
+#: then the warm-up's
+WINDOW_STREAMS = (5, 6)
+WARMUP_STREAMS = (7, 8)
 
 
 @dataclasses.dataclass
 class Request:
-    """One job: which subject, how many iterations and, for a virtual
-    lesion, which bundle of the subject it removes."""
+    """One job: which subject, how many iterations, for a virtual lesion
+    which bundle of the subject it removes, and in an open loop when it is
+    due (seconds from the window's start)."""
 
     subject: int
     n_iters: int
     bundle: Optional[int] = None
+    at: Optional[float] = None
 
 
 def load(path: Path) -> dict:
@@ -35,14 +47,64 @@ def load(path: Path) -> dict:
     for key in ("subjects", "n_iters", "checked"):
         if int(mix.get(key, 1)) < 1:
             raise ValueError(f"{path}: {key} must be a positive integer")
+    if mix["loop"] == "open":
+        if "lesion" in mix:
+            raise ValueError(f"{path}: an open mix has no lesion block")
+        for key in OPEN_POSITIVE + OPEN_NONNEGATIVE:
+            least = "above" if key in OPEN_POSITIVE else "at least"
+            value = float(mix.get(key, -1.0))
+            if value < 0 or (value == 0 and key in OPEN_POSITIVE):
+                raise ValueError(f"{path}: an open mix needs {key}, "
+                                 f"{least} 0")
     return mix
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
     """The generator of one numbered stream of the seed (2: bundles,
-    3: inputs of the isolated calls, 4: the answers checked); subjects'
-    data has streams of its own in ``gen``."""
+    3: inputs of the isolated calls, 4: the answers checked, 5 and 6: an
+    open window's arrival order and subjects, 7 and 8: its warm-up's);
+    subjects' data has streams of its own in ``gen``."""
     return np.random.default_rng([stream, seed % 2 ** 64])
+
+
+def arrivals(rate: float, seconds: float, r: np.random.Generator
+             ) -> np.ndarray:
+    """Send times in ``[0, seconds)`` of an open loop at ``rate`` a second.
+
+    The gaps are the ``n = round(rate * seconds)`` midpoint quantiles of
+    the exponential law, scaled to the mean gap ``seconds / (n + 1/2)``
+    (about ``1 / rate``, and the last arrival inside the window), in the
+    order ``r`` draws: Poisson-like arrivals, with the same number of them
+    and the same total load for every seed, where independent draws would
+    change the load by about ``1 / sqrt(n)`` from seed to seed."""
+    n = max(1, int(round(rate * seconds)))
+    gaps = -np.log1p(-(np.arange(n) + 0.5) / n)
+    gaps *= seconds * n / (n + 0.5) / gaps.sum()
+    return np.cumsum(r.permutation(gaps))
+
+
+def zipf_subjects(count: int, n_subjects: int, exponent: float,
+                  r: np.random.Generator) -> np.ndarray:
+    """``count`` independent draws among ``n_subjects`` tenants, tenant
+    ``k`` with probability proportional to ``(k + 1) ** -exponent``."""
+    p = (np.arange(n_subjects) + 1.0) ** -float(exponent)
+    return r.choice(n_subjects, size=count, p=p / p.sum())
+
+
+def open_requests(mix: dict, seed: int, seconds: float, *,
+                  warm_up: bool = False) -> List[Request]:
+    """The open loop's schedule over ``seconds``: the window's requests on
+    subjects ``0 .. subjects - 1``, or the warm-up's, on the
+    ``warmup_subjects`` after them, from streams of their own."""
+    arrive, pick = WARMUP_STREAMS if warm_up else WINDOW_STREAMS
+    times = arrivals(float(mix["rate_per_s"]), seconds, rng(seed, arrive))
+    n, first = int(mix["subjects"]), 0
+    if warm_up:
+        n, first = int(mix["warmup_subjects"]), n
+    subjects = zipf_subjects(times.size, n, mix["tenants_zipf"],
+                             rng(seed, pick))
+    return [Request(subject=first + int(s), n_iters=int(mix["n_iters"]),
+                    at=float(t)) for s, t in zip(subjects, times)]
 
 
 def closed_requests(mix: dict, seed: int) -> Iterator[Request]:
