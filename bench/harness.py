@@ -7,9 +7,12 @@ its configuration (``bench/configs/<config>.json``), its traffic mix
 (``bench/metrics/<family>.py``, a function ``read(run)``; the metric
 ``idle_share.solve`` is read by ``idle_share.py`` unless a file of its
 full name exists).  The program is driven only through its front end:
-``LifeFrontend.submit_async`` with the front end's default configuration,
-as a user gets it, and for a virtual lesion the program's own edit
-(``repro.science.lesion``), timed with the query.
+``LifeFrontend.submit_async``, as a user gets it: with the front end's
+default configuration, or where the mix names a ``format`` or a ``mesh``
+that configuration with the mix's layout, which every job also asks for;
+and for a virtual lesion the program's own edit (``repro.science.lesion``),
+timed with the query.  Each run starts with an empty plan cache of its
+own (:func:`fresh_plan_cache`), as it starts with cold compiles.
 
 Set-up makes the subjects from the seed (the benchmark's own programs
 compile with the persistent cache on), starts the front end, turns the
@@ -43,14 +46,14 @@ import tempfile
 import threading
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from bench import check, gen, reference, traffic, work
 
 ROOT = Path(__file__).resolve().parents[1]
-#: compile cache, plan cache: fixed paths inside the checkout
+#: the benchmark's own persistent compile cache: a fixed path in the checkout
 CACHE = ROOT / ".bench_cache"
 #: longest wait for one answer
 RESULT_TIMEOUT_S = 300.0
@@ -58,6 +61,7 @@ RESULT_TIMEOUT_S = 300.0
 POLL_S = 0.002
 #: isolated DSC / WC calls per traced run
 ISOLATED_CALLS = 10
+PLAN_CACHE_ENV = "REPRO_PLAN_CACHE"
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
@@ -82,13 +86,16 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
 
 
 def cell_from_files(workload: str, root: Path = ROOT) -> Cell:
-    """The one-chip cell ``<config>.<traffic>`` from its files alone, where
+    """The cell ``<config>.<traffic>`` from its files alone, where
     ``BENCHMARK.json`` does not list it yet: what a sweep for its rate,
-    and the tests, run before the cell is added."""
+    and the tests, run before the cell is added.  It takes as many chips
+    as its mix's mesh holds."""
     spec = json.loads((root / "BENCHMARK.json").read_text())
     config, traffic_name = workload.split(".", 1)
+    mix = traffic.load(root / "bench" / "traffic" / f"{traffic_name}.json")
     return make_cell({"name": workload, "config": config,
-                      "traffic": traffic_name, "chips": 1}, spec, root)
+                      "traffic": traffic_name,
+                      "chips": traffic.chips(mix)}, spec, root)
 
 
 def make_cell(w: dict, spec: dict, root: Path = ROOT) -> Cell:
@@ -121,6 +128,26 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_enable_compilation_cache", True)
     return path
+
+
+@contextlib.contextmanager
+def fresh_plan_cache():
+    """The program's plan cache (``$REPRO_PLAN_CACHE``) in a new empty
+    directory for the block, removed after it: format selection starts
+    equally cold in every run, as compiles do, however many runs one
+    process makes.  The program reads the variable when it builds its
+    cache, which the front end does inside the block."""
+    path = tempfile.mkdtemp(prefix="bench-plans-")
+    old = os.environ.get(PLAN_CACHE_ENV)
+    os.environ[PLAN_CACHE_ENV] = path
+    try:
+        yield path
+    finally:
+        if old is None:
+            os.environ.pop(PLAN_CACHE_ENV, None)
+        else:
+            os.environ[PLAN_CACHE_ENV] = old
+        shutil.rmtree(path, ignore_errors=True)
 
 
 def disable_compile_cache() -> None:
@@ -250,11 +277,16 @@ def to_problem(s: gen.Subject, d: np.ndarray):
                        stats={}, grid=s.grid)
 
 
-def life_config(control: bool):
-    """The front end's default configuration; the control is the program's
-    own bf16-storage path, the nearest precision below fp32."""
+def life_config(control: bool, mix: dict):
+    """The front end's configuration for the mix: its defaults (executor
+    ``opt``, format ``coo``, one device) with the mix's ``format`` and
+    ``mesh`` where it names them.  The control is the same layout on the
+    program's own bf16-storage path, the nearest precision below fp32."""
     from repro.core.life import LifeConfig
-    return LifeConfig(compute_dtype="bf16") if control else LifeConfig()
+    rows, cols = mix.get("mesh", (1, 1))
+    return LifeConfig(format=mix.get("format", "coo"), shard_rows=rows,
+                      shard_cols=cols,
+                      compute_dtype="bf16" if control else "fp32")
 
 
 def _annotate(name: str):
@@ -268,6 +300,7 @@ class Workload:
     def __init__(self, cell: Cell, seed: int, seconds: float):
         cfg, mix = cell.config, cell.mix
         self.cell, self.seed, self.seconds = cell, seed, seconds
+        self.options = traffic.job_options(mix)
         self.d = gen.dictionary(cfg["n_atoms"], cfg["n_theta"])
         lesion = "lesion" in mix
         # a solve mix gets one subject more than the window cycles
@@ -290,14 +323,16 @@ class Workload:
                job_id: Optional[str] = None):
         if req.bundle is None:
             return fe.submit_async(self.problems[req.subject],
-                                   n_iters=req.n_iters, job_id=job_id)
+                                   n_iters=req.n_iters, job_id=job_id,
+                                   **self.options)
         # a virtual lesion as a user makes it: the program's edit of the
         # full subject, then a warm-started re-solve
         from repro.science.lesion import lesion_problem, warm_start_weights
         ids = self.bundles[req.bundle]
         return fe.submit_async(lesion_problem(self.problems[req.subject], ids),
                                n_iters=req.n_iters,
-                               w0=warm_start_weights(self.w_full, ids))
+                               w0=warm_start_weights(self.w_full, ids),
+                               **self.options)
 
     def warm_up(self, fe) -> None:
         """One job of the mix's kind on what the window never sees: the
@@ -317,7 +352,8 @@ class Workload:
             return
         if "lesion" in mix:
             n = int(mix["lesion"]["warm_start_iters"])
-            w, losses = fe.submit_async(self.problems[0], n_iters=n).result(
+            w, losses = fe.submit_async(self.problems[0], n_iters=n,
+                                        **self.options).result(
                 timeout=RESULT_TIMEOUT_S)
             self.w_full, self.w_full_losses = np.asarray(w), losses
             req = traffic.Request(subject=0, n_iters=int(mix["n_iters"]),
@@ -461,12 +497,33 @@ def bench_wc(fn, x):
     return fn(x)
 
 
-class Isolated:
-    """The executor the front end's configuration selects, called alone on
-    subject 0 under jits the benchmark names (``jit_bench_dsc``,
-    ``jit_bench_wc``), compiled in set-up."""
+#: the formats the batched engine chooses among for a job that asks for
+#: ``format="auto"`` (``repro.core.batched``: only these stack under vmap)
+BATCHED_AUTO_FORMATS = ("coo", "alto")
 
-    def __init__(self, wl: Workload, control: bool):
+
+def served_format(problem, config, cache) -> str:
+    """The format the served path ran ``problem`` in under ``config``: the
+    configured one, or for ``"auto"`` the plan the batched engine chose,
+    which the front end's plan ``cache`` holds once a job on the problem
+    ran (resolved as the engine resolves it, so a cached plan is read back
+    and no fresh selection runs)."""
+    if config.format != "auto":
+        return config.format
+    from repro.formats import select as fsel
+    return fsel.resolve_format(problem.phi, problem, config, cache,
+                               allowed=BATCHED_AUTO_FORMATS,
+                               mesh_aware=False).format
+
+
+class Isolated:
+    """The executor that subject 0's jobs ran (:func:`served_format`, on the
+    mix's mesh), called alone on subject 0 under jits the benchmark names
+    (``jit_bench_dsc``, ``jit_bench_wc``), built and compiled after the
+    window, so that it reads what the window's jobs chose and leaves them
+    nothing in the plan cache."""
+
+    def __init__(self, wl: Workload, config, cache):
         import jax
         import jax.numpy as jnp
         from repro.core.registry import create_for_format
@@ -475,8 +532,10 @@ class Isolated:
         self.w = jnp.asarray(rng.uniform(size=s.n_fibers), jnp.float32)
         self.y = jnp.asarray(rng.standard_normal((s.n_voxels, s.b.shape[1])),
                              jnp.float32)
+        self.format = served_format(wl.problems[0], config, cache)
         ex = create_for_format(wl.problems[0].phi, wl.problems[0],
-                               life_config(control))
+                               dataclasses.replace(config, format=self.format))
+        self.executor = ex.name
         self.calls = {
             "dsc": (jax.jit(bench_dsc).lower(ex.matvec, self.w).compile(),
                     ex.matvec, self.w),
@@ -516,26 +575,36 @@ def checked(wl: Workload, jobs: List[Job]) -> List[Job]:
     return [answered[i] for i in sorted(pick)]
 
 
-def compare(wl: Workload, jobs: List[Job]) -> Dict[str, float]:
+def compare(wl: Workload, jobs: List[Job]
+            ) -> Tuple[Dict[str, float], Dict[str, float]]:
     """The checked answers against the reference: one reference solve per
-    distinct subject (or bundle), shared by its jobs."""
+    distinct subject (or bundle), shared by its jobs.  Returns the numbers
+    compared and those only shown (``fit_gap``: ``bench/check.py``)."""
     mix = wl.cell.mix
-    worst_fit, worst_loss = 0.0, 0.0
+    worst_obj, worst_loss, worst_fit = 0.0, 0.0, 0.0
     ref_cache: Dict[object, tuple] = {}
     ones = np.ones(wl.subjects[0].n_fibers, np.float32)
     w_full_ref = None
+
+    def gaps(s, phi, w, losses, ref_w, ref_losses):
+        nonlocal worst_obj, worst_loss, worst_fit
+        worst_obj = max(worst_obj, check.obj_gap(
+            reference.objective(s, wl.d, w),
+            reference.objective(s, wl.d, ref_w)))
+        worst_loss = max(worst_loss, check.loss_gap(losses, ref_losses))
+        worst_fit = max(worst_fit, check.fit_gap(
+            reference.dsc(phi, wl.d, w), reference.dsc(phi, wl.d, ref_w)))
+
     if "lesion" in mix:
         n = int(mix["lesion"]["warm_start_iters"])
-        phi = reference.blocked(wl.subjects[0])
-        w_full_ref, l_full_ref = reference.sbbnnls(
-            phi, wl.d, wl.subjects[0].b, ones, n)
-        worst_fit = check.fit_gap(reference.dsc(phi, wl.d, wl.w_full),
-                                  reference.dsc(phi, wl.d, w_full_ref))
-        worst_loss = check.loss_gap(wl.w_full_losses, l_full_ref)
+        s = wl.subjects[0]
+        phi = reference.blocked(s)
+        w_full_ref, l_full_ref = reference.sbbnnls(phi, wl.d, s.b, ones, n)
         w_full_ref = np.asarray(w_full_ref)
+        gaps(s, phi, wl.w_full, wl.w_full_losses, w_full_ref, l_full_ref)
     picked = checked(wl, jobs)
     if not picked:                      # no answer shows nothing correct
-        worst_fit = float("inf")
+        worst_obj = float("inf")
     for job in picked:
         key = (job.req.subject, job.req.bundle, job.req.n_iters)
         if key not in ref_cache:
@@ -548,14 +617,10 @@ def compare(wl: Workload, jobs: List[Job]) -> Dict[str, float]:
             phi = reference.blocked(s)
             ref_w, ref_losses = reference.sbbnnls(phi, wl.d, s.b, w0,
                                                   job.req.n_iters)
-            ref_cache[key] = (phi, reference.dsc(phi, wl.d, ref_w),
-                              ref_losses)
-        phi, ref_fit, ref_losses = ref_cache[key]
-        worst_fit = max(worst_fit, check.fit_gap(
-            reference.dsc(phi, wl.d, job.w), ref_fit))
-        worst_loss = max(worst_loss, check.loss_gap(job.losses, ref_losses))
-    return {"loss_gap": worst_loss, "fit_gap": worst_fit,
-            "missing": float(missing(jobs))}
+            ref_cache[key] = (s, phi, np.asarray(ref_w), ref_losses)
+        gaps(*ref_cache[key][:2], job.w, job.losses, *ref_cache[key][2:])
+    return ({"loss_gap": worst_loss, "obj_gap": worst_obj,
+             "missing": float(missing(jobs))}, {"fit_gap": worst_fit})
 
 
 def missing(jobs: List[Job]) -> int:
@@ -578,6 +643,12 @@ def peak_bytes(devices) -> Optional[int]:
 def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
         control: bool = False) -> dict:
     """Run the cell once and return its result object (see run.py)."""
+    with fresh_plan_cache():
+        return _run(cell, seed, seconds, traced, t_start, control)
+
+
+def _run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
+         control: bool) -> dict:
     import jax
     from repro.serve.frontend import LifeFrontend
     from bench import peaks, trace
@@ -585,11 +656,13 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
     devices = jax.devices()[:cell.chips]
     readers = {m["name"]: reader(m["name"])
                for m in (cell.per_layer if traced else cell.metrics)}
+    needs_isolated = any(getattr(r, "NEEDS_ISOLATED", False)
+                         for r in readers.values())
     wl = Workload(cell, seed, seconds)
-    fe = LifeFrontend(life_config(control))
-    isolated = (Isolated(wl, control) if any(
-        getattr(r, "NEEDS_ISOLATED", False) for r in readers.values())
-        else None)
+    config = life_config(control, cell.mix)
+    fe = LifeFrontend(config)
+    cache = fe.service.scheduler.cache
+    isolated = None
     disable_compile_cache()
     wl.warm_up(fe)
     counter = CompileCounter()
@@ -602,7 +675,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
         with program_tracing(traced), _annotate("bench.window"):
             jobs = WINDOWS[cell.mix["loop"]](fe, wl)
         counter.on = False
-        if isolated is not None:
+        if needs_isolated:
+            isolated = Isolated(wl, config, cache)
             isolated.run()
         if traced:
             jax.profiler.stop_trace()
@@ -610,6 +684,12 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
         counter.on = False
         counter.close()
         fe.shutdown(drain=False)
+    chosen = {}
+    if config.format == "auto":
+        for j in jobs:
+            if j.latency is not None and j.req.bundle is None:
+                chosen.setdefault(str(j.req.subject), served_format(
+                    wl.problems[j.req.subject], config, cache))
     peak = peak_bytes(devices)
     del fe
     gc.collect()
@@ -621,11 +701,12 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
                  cache_hits_in_window=counter.cache_hits)
     result_trace = None
     if traced:
-        record.peak = peaks.peaks(kind)
+        record.peak = peaks.peaks(kind, len(devices))
         tr = trace.load(_xplane(trace_dir))
         record.trace = trace.summary(tr)
         for op in ("dsc", "wc") if isolated is not None else ():
-            secs = trace.module_seconds(tr, f"bench_{op}")
+            secs = trace.module_seconds(
+                tr, *trace.span(tr, f"bench.isolated.{op}"))
             if secs:
                 record.isolated[op] = {"device_s": float(np.median(secs)),
                                        "work": isolated.work[op]}
@@ -635,7 +716,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
         del tr
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    readings = compare(wl, jobs)
+    readings, shown = compare(wl, jobs)
     if isolated is not None:
         readings.update(isolated.gaps(wl))
     correct, lines = check.verdict(readings, cell.limits)
@@ -650,9 +731,14 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
             "cache_hits_in_window": counter.cache_hits,
             "window_end_s": max((j.finished or 0.0 for j in jobs),
                                 default=0.0),
-            "memory_peak_bytes": peak, "seed": seed,
+            "memory_peak_bytes": peak, "seed": seed, "shown": shown,
             "n_coeffs": [s.n_coeffs for s in wl.subjects][:8],
             "sent_and_latency_s": [[j.sent, j.latency] for j in jobs]}
+    if chosen:
+        info["formats"] = chosen
+    if isolated is not None:
+        info["isolated"] = {"format": isolated.format,
+                            "executor": isolated.executor}
     if cell.mix["loop"] == "open":
         info["open"] = open_summary(jobs, seconds)
     device = {"platform": devices[0].platform, "kind": kind,

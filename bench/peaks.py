@@ -14,9 +14,11 @@ PEAKS = {
 }
 
 
-def peaks(device_kind: str) -> dict:
-    """The peaks of ``device_kind``; raises KeyError for an unknown kind."""
+def peaks(device_kind: str, chips: int = 1) -> dict:
+    """The peaks of ``chips`` devices of ``device_kind`` together (a mesh's
+    work may use all of them at once); raises KeyError for an unknown
+    kind."""
     if device_kind not in PEAKS:
         raise KeyError(f"no published peaks for device kind "
                        f"{device_kind!r} (known: {sorted(PEAKS)})")
-    return PEAKS[device_kind]
+    return {k: chips * v for k, v in PEAKS[device_kind].items()}

@@ -15,6 +15,10 @@ the gradient, the gradient projected onto the free set, a DSC of it, and
 the Barzilai-Borwein step, whose even iterations (counting from 0) take
 one more WC.  The loss recorded for an iteration is that of the weights
 it starts from.
+
+:func:`objective` judges an answer by what it says: ``1/2 |M w - b|^2``
+in float64 on the host (``scipy.sparse``), so its own rounding lies far
+below a float32 solve's.
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+import scipy.sparse
 
 BLOCK = 1 << 16
 
@@ -140,6 +145,19 @@ def sbbnnls(phi: Blocked, d, b, w0, n_iters: int
                              n_fibers=phi.n_fibers, even=it % 2 == 0)
         losses.append(loss)
     return w, np.asarray(jnp.stack(losses))
+
+
+def objective(subject, d, w) -> float:
+    """``1/2 |M w - b|^2`` of weights ``w`` in float64: ``M w`` as the
+    sparse (voxel, atom) matrix of ``w[fiber] * value``, duplicates summed,
+    times the dictionary."""
+    w = np.asarray(w, np.float64).reshape(-1)
+    s = scipy.sparse.csr_matrix(
+        (w[subject.fibers] * np.asarray(subject.values, np.float64),
+         (subject.voxels, subject.atoms)),
+        shape=(subject.n_voxels, np.shape(d)[0]))
+    r = s @ np.asarray(d, np.float64) - np.asarray(subject.b, np.float64)
+    return 0.5 * float(np.vdot(r, r))
 
 
 def lesion(subject, fiber_ids: Sequence[int]):

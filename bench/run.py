@@ -17,7 +17,11 @@ generator lateness, peak device bytes.
 
 It exits 2 with no result when JAX finds no TPU, or fewer chips than the
 cell asks for.  ``--control 1`` runs the program's bf16-storage path in
-place of fp32: it exists to show that the check fails it.
+place of fp32: it exists to show that the check fails it.  The program's
+plan cache is a new empty directory under ``$TMPDIR`` for the run,
+removed at its end (``harness.fresh_plan_cache``); its compiles are cold
+too, while the benchmark's own keep the persistent cache in
+``.bench_cache/jax``.
 """
 from __future__ import annotations
 
@@ -27,7 +31,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -46,7 +49,6 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     from bench import harness
     cell = harness.load_cell(args.workload)
-    os.environ.setdefault("REPRO_PLAN_CACHE", str(harness.CACHE / "plans"))
 
     import jax
     devices = jax.devices()

@@ -112,7 +112,7 @@ def main(argv=None) -> int:
     subjects = gen.subjects(SMALL, 2, seed=11)
     problems = [harness.to_problem(s, d) for s in subjects]
     bundle = traffic.bundles(subjects[0], size=50, count=1, seed=11)[0]
-    fe = LifeFrontend(harness.life_config(False))
+    fe = LifeFrontend(harness.life_config(False, {}))
     trace_dir = tempfile.mkdtemp(prefix="spans-trace-")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0

@@ -82,6 +82,20 @@ def test_sbbnnls_follows_the_program_solver(subject, program):
     np.testing.assert_allclose(w, pw.w, atol=1e-3 * np.abs(w).max())
 
 
+def test_objective_is_half_the_squared_residual_in_float64(subject):
+    d = gen.dictionary(96, 96)
+    w = np.random.default_rng(3).uniform(size=subject.n_fibers)
+    r = _f64_dsc(subject, d, w) - subject.b.astype(np.float64)
+    want = 0.5 * float(np.sum(r * r))
+    assert reference.objective(subject, d, w) == pytest.approx(want,
+                                                               rel=1e-12)
+    # the float32 reference's DSC agrees to its own rounding
+    y32 = np.asarray(reference.dsc(reference.blocked(subject), d, w))
+    r32 = y32 - subject.b
+    assert 0.5 * float(np.sum(r32.astype(np.float64) ** 2)) == (
+        pytest.approx(want, rel=1e-5))
+
+
 def test_lesion_edit_matches_science_lesion(subject):
     import jax.numpy as jnp
     from repro.data.dmri import LifeProblem

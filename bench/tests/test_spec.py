@@ -46,6 +46,14 @@ def test_configs():
             cfg["grid"][2]
 
 
+def chips_fit_the_mix(chips, mix) -> bool:
+    """A cell takes 1 chip or 4, as many as its mix's mesh holds: a
+    four-chip cell's mix carries a mesh of 4 devices, a one-chip cell's
+    none or one of 1."""
+    from bench import traffic
+    return chips in (1, 4) and chips == traffic.chips(mix)
+
+
 def test_workloads_resolve():
     from bench import check, traffic
     names = [w["name"] for w in SPEC["workloads"]]
@@ -55,11 +63,27 @@ def test_workloads_resolve():
     for w in SPEC["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-        assert w["chips"] == 1 and _line(w["why"])
-        traffic.load(ROOT / "bench" / "traffic" / f"{w['traffic']}.json")
+        assert _line(w["why"])
+        mix = traffic.load(ROOT / "bench" / "traffic"
+                           / f"{w['traffic']}.json")
+        assert chips_fit_the_mix(w["chips"], mix)
         limits = check.load_limits(ROOT / "bench" / "limits"
                                    / f"{w['name']}.json")
-        assert {"loss_gap", "fit_gap", "missing"} <= set(limits)
+        assert {"loss_gap", "obj_gap", "missing"} <= set(limits)
+    # at most half of the cells, rounded down, take four chips; one may
+    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
+    assert four <= max(1, len(names) // 2)
+
+
+@pytest.mark.parametrize("chips, mesh, fits", [
+    (1, None, True), (1, [1, 1], True), (4, [2, 2], True),
+    (4, [1, 4], True), (4, None, False), (4, [1, 1], False),
+    (4, [1, 2], False), (1, [2, 2], False), (2, [1, 2], False),
+    (8, [2, 4], False)])
+def test_chips_follow_the_mesh(chips, mesh, fits):
+    mix = {"loop": "closed"} if mesh is None else {"loop": "closed",
+                                                   "mesh": mesh}
+    assert chips_fit_the_mix(chips, mix) is fits
 
 
 def test_metrics():

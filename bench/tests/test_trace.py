@@ -49,6 +49,20 @@ def test_top_ops_and_gaps_are_named():
                                           reverse=True)
 
 
+def test_module_seconds_are_those_started_in_the_span():
+    """Whatever their names: a program compiled alike before the isolated
+    call lends it its name on the device."""
+    t = trace.Trace(ops={}, modules={
+        "/device:TPU:0": trace.Events.of([("jit_dsc(1)", 0.5, 0.75),
+                                          ("jit_dsc(1)", 2.0, 2.5),
+                                          ("jit_other(2)", 3.0, 3.25),
+                                          ("jit_dsc(1)", 3.9, 4.5)]),
+        "/device:TPU:1": trace.Events.of([("jit_dsc(1)", 2.0, 2.25)])},
+        host=trace.Events.of([]))
+    assert sorted(trace.module_seconds(t, 1.0, 4.0)) == pytest.approx(
+        [0.25, 0.25, 0.5, 0.6])
+
+
 def test_work_and_roofline():
     dsc = work.dsc(1_000_000, 140_608, 50_000, 96, 96)
     assert dsc["bytes"] == 16_000_000 + 4 * (96 * 96 + 50_000 + 140_608 * 96)
@@ -68,8 +82,11 @@ def test_recorded_trace():
     s = trace.summary(t)
     assert 0.0 < s["busy_s"] < s["window_s"]
     assert 0.0 < s["idle_share"] < 1.0
-    calls = trace.module_seconds(t, "bench_dsc")
-    assert len(calls) == 2 and all(0 < c < 0.1 for c in calls)
+    # the two bench_dsc calls start inside the window, after two of the
+    # three small calls (the first starts just before it)
+    calls = trace.module_seconds(t, s["lo"], s["hi"])
+    assert len(calls) == 4 and all(0 < c < 0.1 for c in calls)
+    assert sorted(calls)[-2:] == pytest.approx([0.00144, 0.00144], rel=0.01)
     bd = trace.breakdown(t, s["lo"], s["hi"])
     assert bd["device_ops"] and len(bd["device_ops"]) <= 10
     assert bd["idle_gaps"] and len(bd["idle_gaps"]) <= 10

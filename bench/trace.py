@@ -6,7 +6,8 @@ functions below then give
 
 * the busy time, the union of a device's op intervals inside a window;
 * the idle share, one less the busy time over the window's length;
-* the device time of a named jitted program (its module events);
+* the device time of the programs run inside a host annotation (their
+  module events);
 * the ops that took most device time, and the longest idle gaps, each
   gap named by what the host was doing: the benchmark's own annotation
   (``bench.*``) around its middle, and the host event that overlaps it
@@ -121,13 +122,15 @@ def idle_share(trace: Trace, lo: float, hi: float) -> float:
     return 1.0 - busy_s(trace, lo, hi) / (hi - lo)
 
 
-def module_seconds(trace: Trace, fn_name: str) -> List[float]:
-    """Device seconds of each run of the jitted function ``fn_name``
-    (module ``jit_<fn_name>``, on any device)."""
-    want = f"jit_{fn_name}"
+def module_seconds(trace: Trace, lo: float, hi: float) -> List[float]:
+    """Device seconds of each run of a compiled program (an XLA module)
+    that started inside ``[lo, hi]``, on any device.  The benchmark's
+    isolated calls are found by their host annotation and not by the
+    module's name: the runtime shows a program under the name of one
+    compiled alike before it (a format selection's probe of the same DSC
+    on the same subject), so a name alone can miss them."""
     return [float(e - s) for ev in trace.modules.values()
-            for n, s, e in zip(ev.names, ev.start, ev.end)
-            if n == want or n.startswith(want + "(")]
+            for s, e in zip(ev.start, ev.end) if lo <= s <= hi]
 
 
 def top_ops(trace: Trace, lo: float, hi: float,

@@ -8,6 +8,10 @@ answers: exponential gaps at ``rate_per_s``, each seed the same set of
 gaps in its own order, and subjects drawn by popularity (Zipf).  So runs
 with different seeds differ in which subjects they see and when, not in
 how much they ask.
+
+A mix may also name each job's ``format`` and its device ``mesh`` (both
+passed to every job of the run, warm-up included); without them a job
+runs the front end's default layout on one device.
 """
 from __future__ import annotations
 
@@ -26,6 +30,10 @@ OPEN_NONNEGATIVE = ("tenants_zipf",)
 #: then the warm-up's
 WINDOW_STREAMS = (5, 6)
 WARMUP_STREAMS = (7, 8)
+#: the formats a job may name (the serving scheduler's), and those with a
+#: sharded executor, which alone may run on a mesh
+FORMATS = ("auto", "coo", "alto", "sell", "fcoo")
+MESH_FORMATS = ("coo", "sell")
 
 
 @dataclasses.dataclass
@@ -56,7 +64,36 @@ def load(path: Path) -> dict:
             if value < 0 or (value == 0 and key in OPEN_POSITIVE):
                 raise ValueError(f"{path}: an open mix needs {key}, "
                                  f"{least} 0")
+    if mix.get("format", "coo") not in FORMATS:
+        raise ValueError(f"{path}: format must be one of {FORMATS}")
+    if "mesh" in mix:
+        mesh = mix["mesh"]
+        if not (isinstance(mesh, list) and len(mesh) == 2 and all(
+                isinstance(n, int) and not isinstance(n, bool) and n >= 1
+                for n in mesh)):
+            raise ValueError(f"{path}: mesh must be [rows, cols], two "
+                             f"positive integers")
+        if mix.get("format", "coo") not in MESH_FORMATS:
+            raise ValueError(f"{path}: a mesh needs a format with a sharded "
+                             f"executor, one of {MESH_FORMATS}")
     return mix
+
+
+def chips(mix: dict) -> int:
+    """The devices a mix's jobs run on: its mesh's rows times columns."""
+    rows, cols = mix.get("mesh", (1, 1))
+    return rows * cols
+
+
+def job_options(mix: dict) -> dict:
+    """What every job of the mix passes to the front end besides its
+    problem and iterations: the mix's ``format`` and ``mesh``, where set."""
+    out = {}
+    if "format" in mix:
+        out["format"] = mix["format"]
+    if "mesh" in mix:
+        out["mesh"] = tuple(mix["mesh"])
+    return out
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
