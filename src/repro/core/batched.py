@@ -118,6 +118,26 @@ def _pad_sorted(phi: PhiTensor, nc_max: int, sort_dim: Optional[str],
             [phi.values, np.zeros((pad,), phi.values.dtype)]))
 
 
+def prepare_host(phi: PhiTensor, nc: int, dim: Optional[str],
+                 fn: Callable) -> PhiTensor:
+    """One subject's operand for ``fn`` on the host: stably sorted along
+    ``dim`` (None keeps the given order) and padded to ``nc``
+    coefficients.  Nothing runs on the device."""
+    phi = jax.tree_util.tree_map(np.asarray, phi)
+    if dim:
+        order = np.argsort(getattr(phi, dim + "s"), kind="stable")
+        phi = jax.tree_util.tree_map(lambda a: a[order], phi)
+    return _pad_sorted(phi, nc, dim, (fn, dim) in _SEGMENT_SORTED)
+
+
+def alto_ordered(phi: PhiTensor) -> PhiTensor:
+    """``phi`` in ALTO's linearized order, as host arrays: the one ordering
+    the ``alto`` recipe feeds both ops."""
+    from repro.formats.alto import AltoPhi
+    return jax.tree_util.tree_map(
+        np.asarray, AltoPhi.encode(phi).sort()[0].decode())
+
+
 def _stack_phis(phis: Sequence[PhiTensor]) -> PhiTensor:
     return dataclasses.replace(
         phis[0],
@@ -255,10 +275,13 @@ class BatchedLifeEngine:
             # and an explicit format="sell" is rejected by resolve_format.
             from repro.formats import select as fsel
             # mesh_aware=False: shard_rows/cols are placement-only here
-            # (device_put of the stacked operands), so alto stays eligible
+            # (device_put of the stacked operands), so alto stays eligible;
+            # the probes run at the padded size, so a new subject on a
+            # ladder size already probed compiles nothing
             self.format_plan = fsel.resolve_format(
                 self.problems[0].phi, self.problems[0], self.config,
-                self.cache, allowed=("coo", "alto"), mesh_aware=False)
+                self.cache, allowed=("coo", "alto"), mesh_aware=False,
+                probe_nc=self.nc_padded)
             if self.format_plan.format == "alto":
                 self._alto_order = True
                 return None, None, spmv.dsc_naive, spmv.wc_naive
@@ -299,25 +322,16 @@ class BatchedLifeEngine:
         self._compute_dtype = self._resolve_tuning()
         dsc_dim, wc_dim, self._dsc_fn, self._wc_fn = self._resolve_recipe()
 
-        def prep(phi: PhiTensor, dim: Optional[str], fn) -> PhiTensor:
-            if dim:       # stable sort of the host coefficients along dim
-                order = np.argsort(getattr(phi, dim + "s"), kind="stable")
-                phi = jax.tree_util.tree_map(lambda a: a[order], phi)
-            keep_sorted = (fn, dim) in _SEGMENT_SORTED
-            return _pad_sorted(phi, self.nc_padded, dim, keep_sorted)
-
         phis = [p.phi for p in self.problems]
         if self._alto_order:
             # one ALTO-linearized ordering per subject serves both ops
             # (locality in every mode at once; scatter executors above)
-            from repro.formats.alto import AltoPhi
-            phis = [AltoPhi.encode(phi).sort()[0].decode() for phi in phis]
-        phis = [jax.tree_util.tree_map(np.asarray, phi) for phi in phis]
+            phis = [alto_ordered(phi) for phi in phis]
 
-        phi_dsc = _stack_phis(
-            [prep(phi, dsc_dim, self._dsc_fn) for phi in phis])
-        phi_wc = _stack_phis(
-            [prep(phi, wc_dim, self._wc_fn) for phi in phis])
+        phi_dsc = _stack_phis([prepare_host(phi, self.nc_padded, dsc_dim,
+                                            self._dsc_fn) for phi in phis])
+        phi_wc = _stack_phis([prepare_host(phi, self.nc_padded, wc_dim,
+                                           self._wc_fn) for phi in phis])
         # the signals keep their device copies: one stack at (S, Nv, Ntheta),
         # a shape every subject of the geometry shares
         b = jnp.stack([p.b for p in self.problems])

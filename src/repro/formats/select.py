@@ -22,14 +22,16 @@ Phi.  The decision pipeline:
    degrees: padding would dominate bytes moved).
 4. **Autotune fallback** — whenever more than one candidate survives the
    heuristic (SELL in its ambiguous zone, or COO vs ALTO with no static
-   signal between them), measure: the same three-runs-per-candidate loop
-   as the paper's runtime restructuring selection, literally reused from
-   ``restructure.autotune_plan`` with the format encoders plugged in as
-   the ``sorter`` and the DSC executors (the dominant op, ~2
-   calls/iteration vs WC's ~1.5) as the ``run``.  ``autotune_plan`` in
-   turn times through :mod:`repro.tune.search` — the same measurement
-   loop the kernel autotuner uses — so format choice and tile choice
-   share one cost currency (DESIGN.md §10.2).
+   signal between them), measure: the paper's three runs per candidate of
+   the DSC executor (the dominant op, ~2 calls/iteration vs WC's ~1.5),
+   timed through :mod:`repro.tune.search` — the same measurement loop
+   ``restructure.autotune_plan`` and the kernel autotuner use — so format
+   choice and tile choice share one cost currency (DESIGN.md §10.2).
+   A caller that runs at a padded size passes it as ``probe_nc``: the
+   batched engine's candidates are then prepared on the host as the engine
+   prepares them, padded to that size and timed there, so a new subject
+   whose size was probed before compiles nothing.  Without ``probe_nc``
+   the probes run at the subject's exact Nc.
 
 ``resolve_format`` is the engine entry point: it also handles explicit
 ``LifeConfig(format="sell"/"alto"/"coo")`` requests (no selection, plan
@@ -40,13 +42,14 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import spmv
+from repro.core import batched, spmv
 from repro.core.inspector import phi_stats
-from repro.core.restructure import autotune_plan, sort_by_host
+from repro.core.restructure import sort_by_host
 from repro.core.std import PhiTensor
 from repro.formats import fcoo as fcoo_mod
 from repro.formats import sell as sell_mod
@@ -114,8 +117,12 @@ def choose_format(
     sell_reject: float = DEFAULT_SELL_REJECT,
     cache=None,
     predictor=None,
+    probe_nc: Optional[int] = None,
 ) -> FormatPlan:
-    """Pick a Phi format for one dataset (see module docstring pipeline)."""
+    """Pick a Phi format for one dataset (see module docstring pipeline).
+
+    ``probe_nc`` is the padded coefficient count the caller runs at; the
+    measure rung then probes at that size (None: at ``phi``'s own)."""
     if not allowed:
         raise ValueError("allowed must name at least one format")
     key = None
@@ -134,7 +141,7 @@ def choose_format(
                 # refined (process restart dropped the queue) — re-enqueue
                 _enqueue_refinement(key, cache, phi, dictionary, allowed,
                                     row_tile, slot_tile, sell_accept,
-                                    sell_reject)
+                                    sell_reject, probe_nc)
             return plan
 
     stats = phi_stats(phi, row_tile=row_tile, slot_tile=slot_tile)
@@ -151,20 +158,22 @@ def choose_format(
                 cache.put_format_plan(key, plan)
                 _enqueue_refinement(key, cache, phi, dictionary, allowed,
                                     row_tile, slot_tile, sell_accept,
-                                    sell_reject)
+                                    sell_reject, probe_nc)
             return plan
         obs.counter("learn.predict", kind="format", outcome="fallback").inc()
 
     plan = _decide_format(phi, dictionary, stats, params, allowed,
                           row_tile=row_tile, slot_tile=slot_tile,
-                          sell_accept=sell_accept, sell_reject=sell_reject)
+                          sell_accept=sell_accept, sell_reject=sell_reject,
+                          probe_nc=probe_nc)
     if key is not None:
         cache.put_format_plan(key, plan)
     return plan
 
 
 def _decide_format(phi, dictionary, stats, params, allowed, *, row_tile,
-                   slot_tile, sell_accept, sell_reject) -> FormatPlan:
+                   slot_tile, sell_accept, sell_reject,
+                   probe_nc=None) -> FormatPlan:
     """Heuristic + measured rungs of the ladder (no cache, no predictor).
 
     Factored out so background refinement can re-run exactly this under
@@ -180,13 +189,15 @@ def _decide_format(phi, dictionary, stats, params, allowed, *, row_tile,
     if len(candidates) == 1:
         return FormatPlan(candidates[0], "heuristic", params, stats)
     return FormatPlan(_measure_formats(phi, dictionary, candidates,
-                                       row_tile, slot_tile),
+                                       row_tile, slot_tile, probe_nc),
                       "autotune", params, stats)
 
 
 def _enqueue_refinement(key, cache, phi, dictionary, allowed, row_tile,
-                        slot_tile, sell_accept, sell_reject) -> None:
-    """Queue the measured pipeline to upgrade a predicted plan in place."""
+                        slot_tile, sell_accept, sell_reject,
+                        probe_nc=None) -> None:
+    """Queue the measured pipeline to upgrade a predicted plan in place,
+    probing at the size the predicted request runs at."""
     from repro.learn import refine
 
     def _task() -> None:
@@ -195,22 +206,57 @@ def _enqueue_refinement(key, cache, phi, dictionary, allowed, row_tile,
         plan = _decide_format(phi, dictionary, stats, params, allowed,
                               row_tile=row_tile, slot_tile=slot_tile,
                               sell_accept=sell_accept,
-                              sell_reject=sell_reject)
+                              sell_reject=sell_reject, probe_nc=probe_nc)
         cache.put_format_plan(key, plan)
 
     refine.QUEUE.push("format", key, _task)
 
 
+#: probe shapes this process has timed — (format, Nc probed, Nv, Nf,
+#: dictionary shape, dtype) — so ``select.probe`` can say whether a probe's
+#: programs were compiled before
+_PROBED = set()
+
+
+def _padded_operand(phi: PhiTensor, fmt: str, nc: int):
+    """A coo or alto probe as the batched engine builds its operand: the
+    subject ordered on the host (voxel sort; ALTO's order), padded to
+    ``nc`` inert coefficients and placed on the device once.  Returns it
+    with the DSC function the engine's recipe runs on it."""
+    if fmt == "alto":
+        phi, dim, dsc = batched.alto_ordered(phi), None, spmv.dsc_naive
+    else:
+        dim, dsc = "voxel", spmv.dsc
+    return jax.device_put(batched.prepare_host(phi, nc, dim, dsc)), dsc
+
+
 def _measure_formats(phi: PhiTensor, dictionary, allowed: Tuple[str, ...],
-                     row_tile: int, slot_tile: int) -> str:
-    """Autotune fallback: time the DSC executor of each candidate format
-    through restructure.autotune_plan's measurement loop."""
+                     row_tile: int, slot_tile: int,
+                     probe_nc: Optional[int] = None) -> str:
+    """Autotune fallback: time the DSC executor of each candidate format,
+    one warm-up and three timed calls each (:mod:`repro.tune.search`).
+
+    With ``probe_nc`` the coo and alto candidates are built on the host as
+    the batched engine builds its operands (``core/batched.py``), padded to
+    ``probe_nc`` inert coefficients, placed on the device once and timed
+    on the engine's own DSC functions: every subject that runs at one
+    padded size shares the probes' compiled programs.  Sell and fcoo, whose
+    encoded shapes are the subject's own, probe at its exact Nc."""
+    from repro.tune import search as tsearch
+    if probe_nc is not None and probe_nc < phi.n_coeffs:
+        raise ValueError(f"probe_nc={probe_nc} is below the subject's "
+                         f"{phi.n_coeffs} coefficients")
     w_probe = jnp.ones((phi.n_fibers,), dictionary.dtype)
 
-    def sorter(p: PhiTensor, fmt: str):
+    def probe(fmt: str, padded: bool):
+        """The candidate's prepared DSC call."""
+        if padded:
+            op, dsc = _padded_operand(phi, fmt, probe_nc)
+            return lambda: dsc(op, dictionary, w_probe)
         if fmt == "sell":
-            return SellPhi.encode(p, op="dsc", row_tile=row_tile,
-                                  slot_tile=slot_tile), None
+            enc = SellPhi.encode(phi, op="dsc", row_tile=row_tile,
+                                 slot_tile=slot_tile)
+            return lambda: sell_mod.dsc_reference(enc, dictionary, w_probe)
         if fmt == "alto":
             # prepare the actual registry executor so arbitration charges
             # ALTO whatever its real DSC path costs — timing dsc_naive over
@@ -220,30 +266,36 @@ def _measure_formats(phi: PhiTensor, dictionary, allowed: Tuple[str, ...],
             from types import SimpleNamespace
             from repro.core.registry import REGISTRY
             ex = REGISTRY.create(
-                "alto", p, SimpleNamespace(dictionary=dictionary),
+                "alto", phi, SimpleNamespace(dictionary=dictionary),
                 SimpleNamespace(tune="off"))
-            return ex, None
+            return lambda: ex.matvec(w_probe)
         if fmt == "fcoo":
-            return FcooPhi.encode(p), None
-        return sort_by_host(p, "voxel")            # coo
+            enc = FcooPhi.encode(phi)
+            return lambda: fcoo_mod.dsc_reference(enc, dictionary, w_probe)
+        op, _ = sort_by_host(phi, "voxel")              # coo
+        return lambda: spmv.dsc(op, dictionary, w_probe)
 
-    def run(prepared, fmt: str):
-        if fmt == "sell":
-            return sell_mod.dsc_reference(prepared, dictionary, w_probe)
-        if fmt == "alto":
-            return prepared.matvec(w_probe)        # the registry executor
-        if fmt == "fcoo":
-            return fcoo_mod.dsc_reference(prepared, dictionary, w_probe)
-        return spmv.dsc(prepared, dictionary, w_probe)  # coo, voxel-sorted
+    def measure(fmt: str) -> float:
+        padded = probe_nc is not None and fmt in ("coo", "alto")
+        nc_probe = probe_nc if padded else phi.n_coeffs
+        shape = (fmt, nc_probe, phi.n_voxels, phi.n_fibers,
+                 tuple(dictionary.shape), str(dictionary.dtype))
+        obs.counter("select.probe", format=fmt,
+                    shape="seen" if shape in _PROBED else "new").inc()
+        with obs.span("select.measure", {"format": fmt, "nc": phi.n_coeffs,
+                                         "nc_probe": nc_probe}):
+            cost = tsearch.time_call(probe(fmt, padded), warmup=1, repeats=3)
+        _PROBED.add(shape)
+        return cost
 
-    plan = autotune_plan("dsc", phi, run, candidates=tuple(allowed),
-                         sorter=sorter)
-    return plan.restructure                        # holds the format name
+    best_i, _ = tsearch.measure_candidates(tuple(allowed), measure)
+    return tuple(allowed)[best_i]
 
 
 def resolve_format(phi: PhiTensor, problem, config, cache=None,
                    allowed: Optional[Tuple[str, ...]] = None,
-                   mesh_aware: bool = True) -> FormatPlan:
+                   mesh_aware: bool = True,
+                   probe_nc: Optional[int] = None) -> FormatPlan:
     """Engine entry point: honor an explicit ``config.format`` or select.
 
     ``allowed`` restricts the candidate set (the batched engine passes the
@@ -254,7 +306,9 @@ def resolve_format(phi: PhiTensor, problem, config, cache=None,
     drop the requested partitioning.  Callers for whom the mesh is
     placement-only (the batched engine: ``shard_rows/cols`` just
     device_put the stacked operands, no mesh executor runs) pass
-    ``mesh_aware=False`` to keep the full candidate set.
+    ``mesh_aware=False`` to keep the full candidate set.  ``probe_nc``
+    is the padded coefficient count the caller runs at (the batched
+    engine's ladder size); the measure rung probes there.
     """
     fmt = getattr(config, "format", "coo")
     row_tile, slot_tile = _geometry(config)
@@ -289,4 +343,4 @@ def resolve_format(phi: PhiTensor, problem, config, cache=None,
         allowed=candidates,
         sell_accept=getattr(config, "sell_accept", DEFAULT_SELL_ACCEPT),
         sell_reject=getattr(config, "sell_reject", DEFAULT_SELL_REJECT),
-        cache=cache, predictor=predictor)
+        cache=cache, predictor=predictor, probe_nc=probe_nc)

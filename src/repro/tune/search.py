@@ -3,8 +3,8 @@
 The paper selects its restructuring at runtime from "the average execution
 time for three runs"; this module is that loop factored out once, so the
 three searches that exist today — restructuring choice
-(``core/restructure.autotune_plan``), format choice (``formats/select``,
-via ``autotune_plan``), and kernel launch parameters (``tune/tuner``) —
+(``core/restructure.autotune_plan``), format choice (``formats/select``),
+and kernel launch parameters (``tune/tuner``) —
 measure with identical warmup/blocking/repeat semantics and their outcomes
 stay comparable.
 

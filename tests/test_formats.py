@@ -1,4 +1,6 @@
 """Sparse-format subsystem: round-trips, SELL kernels vs oracle, selection."""
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -404,3 +406,151 @@ def test_measure_formats_times_registry_alto_executor(tiny_problem,
                                 ("coo", "alto"), 8, 32)
     assert built, "arbitration must build the registry alto executor"
     assert fmt in ("coo", "alto")
+
+
+# ----------------------------------------------------------------------------
+# Probing at the caller's padded size
+# ----------------------------------------------------------------------------
+
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def _compiles():
+    """Collects the XLA backend compiles made inside the block."""
+    import jax.monitoring as mon
+    seen = []
+
+    def count(event, *_, **__):
+        if event == BACKEND_COMPILE:
+            seen.append(event)
+
+    mon.register_event_duration_secs_listener(count)
+    try:
+        yield seen
+    finally:
+        mon.unregister_event_duration_listener(count)
+
+
+def _same_rung_subjects(problem, n):
+    """``n`` subjects of distinct Nc on one ladder size: leading slices of
+    ``problem``'s coefficients, each scaled differently."""
+    import dataclasses
+
+    import jax
+    from repro.core.batched import ladder_size
+    top = problem.phi.n_coeffs
+    if ladder_size(top - n) != ladder_size(top):
+        top = ladder_size(top - n)        # a ladder value below Nc
+    subjects = []
+    for k in range(n):
+        phi = jax.tree_util.tree_map(lambda a: a[:top - k], problem.phi)
+        subjects.append(dataclasses.replace(problem, phi=dataclasses.replace(
+            phi, values=phi.values * (1.0 + k))))
+    assert len({ladder_size(s.phi.n_coeffs) for s in subjects}) == 1
+    return subjects
+
+
+def _measure_spans():
+    from repro import obs
+    return [e["args"] for e in obs.TRACER.export_chrome()
+            if e["name"] == "select.measure"]
+
+
+def test_padded_probes_compile_nothing_for_a_new_subject_on_the_rung(
+        tiny_problem, tmp_path, monkeypatch):
+    """A second subject on the ladder size already probed reuses the
+    probes' programs, in ``choose_format`` and in a batched engine's
+    build and first step."""
+    import jax
+    from repro import obs
+    from repro.core.batched import BatchedLifeEngine, ladder_size
+    from repro.core.life import LifeConfig
+    monkeypatch.setattr(fsel, "_PROBED", set())
+    a, b, c = _same_rung_subjects(tiny_problem, 3)
+    rung = ladder_size(a.phi.n_coeffs)
+    d = tiny_problem.dictionary
+    obs.enable()
+    fsel.choose_format(a.phi, d, allowed=("coo", "alto"), probe_nc=rung)
+    with _compiles() as seen:
+        plan = fsel.choose_format(b.phi, d, allowed=("coo", "alto"),
+                                  probe_nc=rung)
+    assert plan.reason == "autotune" and plan.format in ("coo", "alto")
+    assert seen == []
+    for fmt in ("coo", "alto"):
+        assert obs.value("select.probe", format=fmt, shape="new") == 1
+        assert obs.value("select.probe", format=fmt, shape="seen") == 1
+    assert {(s["format"], s["nc_probe"]) for s in _measure_spans()} == {
+        ("coo", rung), ("alto", rung)}
+    assert {s["nc"] for s in _measure_spans()} == {a.phi.n_coeffs,
+                                                   b.phi.n_coeffs}
+
+    def build_and_step(problem, fmt):
+        eng = BatchedLifeEngine([problem], LifeConfig(
+            executor="opt", format=fmt, n_iters=2,
+            plan_cache_dir=str(tmp_path)))
+        states, _ = eng.step(eng.init_states(), 2)
+        jax.block_until_ready(states)
+        return eng
+
+    for fmt in ("coo", "alto"):       # both runners the choice may pick
+        build_and_step(a, fmt)
+    with _compiles() as seen:
+        eng = build_and_step(c, "auto")
+    assert eng.nc_padded == rung
+    assert eng.format_plan.reason == "autotune"
+    assert seen == []
+
+
+@pytest.mark.parametrize("fmt", ["coo", "alto"])
+def test_padded_probe_is_inert(tiny_problem, rng, fmt):
+    """The padded probe's DSC equals the engine function's on the real
+    coefficients alone, to fp32 round-off."""
+    from repro.core.restructure import sort_by_host
+    phi, d = tiny_problem.phi, tiny_problem.dictionary
+    w = jnp.asarray(rng.uniform(size=phi.n_fibers), jnp.float32)
+    op, dsc = fsel._padded_operand(phi, fmt, phi.n_coeffs + 37)
+    assert op.n_coeffs == phi.n_coeffs + 37
+    if fmt == "coo":
+        assert dsc is spmv.dsc
+        assert np.all(np.diff(np.asarray(op.voxels)) >= 0)  # still sorted
+        want = spmv.dsc(sort_by_host(phi, "voxel")[0], d, w)
+    else:
+        assert dsc is spmv.dsc_naive
+        want = spmv.dsc_naive(phi, d, w)
+    np.testing.assert_allclose(np.asarray(dsc(op, d, w)), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_probe_nc_below_the_subject_is_refused(tiny_problem):
+    with pytest.raises(ValueError, match="probe_nc"):
+        fsel._measure_formats(tiny_problem.phi, tiny_problem.dictionary,
+                              ("coo", "alto"), 8, 32,
+                              probe_nc=tiny_problem.phi.n_coeffs - 1)
+
+
+def test_single_subject_engine_probes_at_exact_nc(tiny_problem):
+    from repro import obs
+    from repro.core.life import LifeConfig, LifeEngine
+    obs.enable()
+    eng = LifeEngine(tiny_problem, LifeConfig(
+        format="auto", sell_accept=-1.0, plan_cache_dir=""))
+    assert eng.format_plan.reason == "autotune"
+    spans = _measure_spans()
+    assert len(spans) >= 2
+    nc = tiny_problem.phi.n_coeffs
+    assert all(s["nc"] == s["nc_probe"] == nc for s in spans)
+
+
+def test_sell_and_fcoo_probe_at_exact_nc_under_probe_nc(tiny_problem):
+    """Only coo and alto are padded: sell and fcoo encode the subject's
+    own shapes whatever size the caller runs at."""
+    from repro import obs
+    nc = tiny_problem.phi.n_coeffs
+    obs.enable()
+    plan = fsel.choose_format(tiny_problem.phi, tiny_problem.dictionary,
+                              sell_accept=-1.0, sell_reject=float("inf"),
+                              probe_nc=nc + 40)
+    assert plan.reason == "autotune"
+    assert {(s["format"], s["nc_probe"]) for s in _measure_spans()} == {
+        ("coo", nc + 40), ("alto", nc + 40), ("sell", nc), ("fcoo", nc)}

@@ -321,6 +321,34 @@ def test_format_refinement_upgrades_predicted_plan(tmp_path, tiny_problem,
     assert upgraded.reason in ("heuristic", "autotune")
 
 
+def test_format_refinement_probes_at_the_enqueued_size(tmp_path,
+                                                      tiny_problem,
+                                                      monkeypatch):
+    """A predicted plan's refinement measures at the ``probe_nc`` of the
+    request it refines, not at the subject's exact Nc."""
+    from repro import obs
+    from repro.formats import select as fsel
+    cache, predictor = _trained_cache(tmp_path / "train")
+    fresh = PlanCache(cache.directory)
+    nc_probe = tiny_problem.phi.n_coeffs + 40
+    monkeypatch.setattr(fsel, "_measure_formats", _boom)
+    plan = fsel.choose_format(tiny_problem.phi, tiny_problem.dictionary,
+                              allowed=("coo", "alto"), cache=fresh,
+                              predictor=predictor, probe_nc=nc_probe)
+    assert plan.reason == "predicted"
+    monkeypatch.undo()
+    obs.enable()
+    assert run_pending() >= 1
+    probes = [e["args"] for e in obs.TRACER.export_chrome()
+              if e["name"] == "select.measure"]
+    assert {p["format"] for p in probes} == {"coo", "alto"}
+    assert all(p["nc_probe"] == nc_probe for p in probes)
+    upgraded = fsel.choose_format(tiny_problem.phi, tiny_problem.dictionary,
+                                  allowed=("coo", "alto"), cache=fresh,
+                                  predictor=predictor)
+    assert upgraded.reason == "autotune"
+
+
 def test_cache_hit_on_predicted_plan_reenqueues_refinement(tmp_path,
                                                            tiny_problem,
                                                            monkeypatch):
